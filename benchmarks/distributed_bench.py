@@ -13,14 +13,14 @@ import jax.numpy as jnp
 
 from repro.core import generate_problem, sketched_lstsq
 from repro.core.distributed import shard_rows
+from repro.sharding import make_mesh
 
 from .common import emit, time_fn
 
 
 def run(m=32768, n=128, seed=0):
     ndev = len(jax.devices())
-    mesh = jax.make_mesh(
-        (ndev,), ("data",))
+    mesh = make_mesh((ndev,), ("data",))
     prob = generate_problem(
         jax.random.key(seed), m, n, cond=1e10, beta=1e-10, method="fast"
     )

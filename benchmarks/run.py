@@ -31,7 +31,10 @@ import sys
 def main() -> None:
     import jax
 
+    from .common import use_compile_cache
+
     jax.config.update("jax_enable_x64", True)
+    use_compile_cache()
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,

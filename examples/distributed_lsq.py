@@ -1,17 +1,16 @@
-"""Distributed sketch-and-solve: row-sharded A over 8 (simulated) devices.
+"""Distributed sketch-and-solve: row-sharded A over every device JAX sees.
 
 Each shard applies the shared ``CountSketch`` operator to its local rows
 (into the global bucket space); one s x (n+1) all-reduce assembles the
 sketch; LSQR runs distributed with psum-reduced inner products.
 Communication is independent of m.  ``--backend pallas`` routes the local
-applies through the Pallas kernel (interpret mode off-TPU).
+applies through the Pallas kernel (interpret mode off-TPU).  On a CPU host,
+simulate devices by setting ``XLA_FLAGS`` before the run:
 
-    PYTHONPATH=src python examples/distributed_lsq.py [--backend auto]
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        PYTHONPATH=src python examples/distributed_lsq.py [--backend auto]
 """
 import argparse
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import jax
 
@@ -20,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.core import generate_problem, qr_solve, sketched_lstsq
 from repro.core.distributed import shard_rows
+from repro.sharding import make_mesh
 
 
 def main():
@@ -28,7 +28,7 @@ def main():
                     default="auto", help="local sketch-apply backend")
     args = ap.parse_args()
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((len(jax.devices()),), ("data",))
     m, n = 65536, 128
     prob = generate_problem(jax.random.key(0), m, n, cond=1e8, beta=1e-10)
     A, b = shard_rows(mesh, ("data",), prob.A, prob.b)

@@ -52,6 +52,7 @@ import time
 
 import jax.numpy as jnp
 
+from ..kernels.common import matmul
 from ..obs import trace as obs_trace
 from ..obs.lockcheck import make_lock
 from ..obs.metrics import REGISTRY
@@ -583,7 +584,7 @@ class ClusterEngine(RowSource):
             parts = []
             for _local_o, tile in sub.tiles():
                 self._fault_gate(worker, "matvec")
-                parts.append(jnp.asarray(tile) @ x)
+                parts.append(matmul(jnp.asarray(tile), x))
                 worker.beat()
                 self._count_tiles()
             return jnp.concatenate(parts, axis=0)
@@ -603,7 +604,7 @@ class ClusterEngine(RowSource):
                 self._fault_gate(worker, "matvec")
                 tile = jnp.asarray(tile)
                 gl = rng.start + local_o
-                g = g + tile.T @ u[gl : gl + tile.shape[0]]
+                g = g + matmul(tile.T, u[gl : gl + tile.shape[0]])
                 worker.beat()
                 self._count_tiles()
             return g
@@ -627,8 +628,8 @@ class ClusterEngine(RowSource):
                 self._fault_gate(worker, "matvec")
                 tile = jnp.asarray(tile)
                 gl = rng.start + local_o
-                r_t = b[gl : gl + tile.shape[0]] - tile @ x
-                g = g + tile.T @ r_t
+                r_t = b[gl : gl + tile.shape[0]] - matmul(tile, x)
+                g = g + matmul(tile.T, r_t)
                 rn2 = rn2 + jnp.sum(r_t * r_t, axis=0)
                 worker.beat()
                 self._count_tiles()

@@ -41,6 +41,7 @@ __all__ = [
     "default_interpret",
     "kernel_backed",
     "kernel_blocks",
+    "INTERPRET_TARGET",
 ]
 
 BACKENDS = ("auto", "reference", "pallas")
@@ -103,23 +104,29 @@ def kernel_backed(kind: str) -> bool:
     return kind in KERNEL_BACKED_KINDS
 
 
-def kernel_blocks(kind: str, m: int, n: int, d: int, dtype) -> dict:
+# Off-TPU the kernels run in interpret mode with the tiles they would use
+# on this chip, so CPU tests cover the chip's tiling.
+INTERPRET_TARGET = "TPU v5 lite"
+
+
+def kernel_blocks(
+    kind: str, m: int, n: int, d: int, dtype, *, interpret: bool
+) -> dict:
     """Autotuned block-shape kwargs for a kernel dispatch site.
 
     Consults ``repro.kernels.autotune`` (committed cache first, roofline cost
-    model on miss) and returns kwargs splat-able into the kernel wrapper —
-    ``{}`` means "use the kernel's hand-tuned defaults", which is also the
-    answer whenever the tuner is disabled (``REPRO_AUTOTUNE=0``) or
-    unavailable.  Never raises: tuning is advisory, dispatch must not fail.
+    model on miss) for the attached device — or, in interpret mode, for
+    :data:`INTERPRET_TARGET` — and returns kwargs splat-able into the kernel
+    wrapper.  ``{}`` means "use the kernel's hand-tuned defaults", which is
+    the answer when the tuner is disabled (``REPRO_AUTOTUNE=0``).  Tuner
+    errors (such as a device with no peak table) propagate.
     """
     if os.environ.get("REPRO_AUTOTUNE", "1") == "0":
         return {}
-    try:
-        from ..kernels.autotune import best_blocks
+    from ..kernels.autotune import best_blocks
 
-        return best_blocks(kind, m, n, d, dtype)
-    except Exception:
-        return {}
+    device = INTERPRET_TARGET if interpret else jax.devices()[0].device_kind
+    return best_blocks(kind, m, n, d, dtype, device=device)
 
 
 def resolve_fused(fused: bool | None) -> bool:

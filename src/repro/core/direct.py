@@ -5,6 +5,8 @@ import jax
 import jax.numpy as jnp
 from jax.scipy.linalg import solve_triangular
 
+from ..kernels.common import matmul
+
 __all__ = ["qr_solve", "svd_solve", "normal_equations"]
 
 
@@ -12,7 +14,7 @@ __all__ = ["qr_solve", "svd_solve", "normal_equations"]
 def qr_solve(A: jax.Array, b: jax.Array) -> jax.Array:
     """x = R⁻¹ Qᵀ b via reduced Householder QR of A."""
     Q, R = jnp.linalg.qr(A, mode="reduced")
-    return solve_triangular(R, Q.T @ b, lower=False)
+    return solve_triangular(R, matmul(Q.T, b), lower=False)
 
 
 @jax.jit
@@ -25,5 +27,5 @@ def svd_solve(A: jax.Array, b: jax.Array, rcond: float | None = None) -> jax.Arr
 @jax.jit
 def normal_equations(A: jax.Array, b: jax.Array) -> jax.Array:
     """Cholesky on AᵀA — fast, squares the condition number (for comparison)."""
-    G = A.T @ A
-    return jax.scipy.linalg.cho_solve(jax.scipy.linalg.cho_factor(G), A.T @ b)
+    G = matmul(A.T, A)
+    return jax.scipy.linalg.cho_solve(jax.scipy.linalg.cho_factor(G), matmul(A.T, b))

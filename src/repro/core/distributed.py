@@ -26,16 +26,17 @@ collective term is O(s·n) per solve + O(n) per LSQR iteration.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..sharding import shard_map_compat
 from . import backend as backend_lib
 from . import linop
 from . import sketch as sketch_lib
+from ..kernels.common import vdot
 from .lsqr import lsqr
 from .precond import SketchedFactor, default_sketch_size
 from .result import SolveResult
@@ -99,13 +100,9 @@ def sketched_lstsq(
     matrix-free solvers for those.
     """
     A = linop.ensure_dense(A, who="the distributed row-sharded driver")
-    backend = backend_lib.resolve(backend).name
     if isinstance(axes, str):
         axes = (axes,)
     m, n = A.shape
-    s = sketch_size if sketch_size is not None else default_sketch_size(n, m)
-    if steptol is None:
-        steptol = 32 * float(jnp.finfo(A.dtype).eps)
     cls = sketch_lib.SKETCH_KINDS.get(sketch)
     if cls is None:
         raise ValueError(
@@ -118,6 +115,33 @@ def sketched_lstsq(
             "distributed driver supports the scatter kinds "
             "(clarkson_woodruff/countsketch, sparse_sign, uniform_sparse)"
         )
+    if steptol is None:
+        steptol = 32 * float(jnp.finfo(A.dtype).eps)
+    x, istop, itn, rnorm, arnorm = _solve(
+        A, b, key, mesh=mesh, axes=tuple(axes), sketch=sketch,
+        s=sketch_size if sketch_size is not None else default_sketch_size(n, m),
+        atol=float(atol), btol=float(btol), steptol=float(steptol),
+        iter_lim=int(iter_lim), backend=backend_lib.resolve(backend).name,
+    )
+    return SolveResult(
+        x=x, istop=istop, itn=itn, rnorm=rnorm, arnorm=arnorm,
+        used_fallback=jnp.asarray(False),
+    )
+
+
+# One compiled program per (mesh, axes, sketch, size, tolerances, backend):
+# repeated solves with the same settings reuse it.
+@partial(
+    jax.jit,
+    static_argnames=(
+        "mesh", "axes", "sketch", "s", "atol", "btol", "steptol",
+        "iter_lim", "backend",
+    ),
+)
+def _solve(A, b, key, *, mesh, axes, sketch, s, atol, btol, steptol,
+           iter_lim, backend):
+    m, n = A.shape
+    cls = sketch_lib.SKETCH_KINDS[sketch]
     # One global operator draw, shared by every shard; its per-row
     # parameter arrays row-shard with A.
     op = cls.sample(key, s, m, dtype=A.dtype)
@@ -153,7 +177,7 @@ def sketched_lstsq(
             return lax.psum(factor.whiten_rmv(A_i, u), axes)
 
         def udot(u, w):
-            return lax.psum(jnp.vdot(u, w), axes)
+            return lax.psum(vdot(u, w), axes)
 
         res = lsqr(
             mv, rmv, b_i, x0=z0, n=n, atol=atol, btol=btol,
@@ -162,15 +186,11 @@ def sketched_lstsq(
         x = factor.precondition(res.x)
         return x, res.istop, res.itn, res.rnorm, res.arnorm
 
-    row = P(axes)
-    fn = shard_map_compat(
+    # Outputs are psum-fed, hence replicated; the replication check is off.
+    return jax.shard_map(
         local_solve,
         mesh=mesh,
-        in_specs=(P(axes, None), row) + (param_spec,) * len(params),
+        in_specs=(P(axes, None), P(axes)) + (param_spec,) * len(params),
         out_specs=(P(), P(), P(), P(), P()),
-    )
-    x, istop, itn, rnorm, arnorm = fn(A, b, *params)
-    return SolveResult(
-        x=x, istop=istop, itn=itn, rnorm=rnorm, arnorm=arnorm,
-        used_fallback=jnp.asarray(False),
-    )
+        check_vma=False,
+    )(A, b, *params)

@@ -28,7 +28,7 @@ All concrete operators are registered JAX pytrees (array payloads are
 leaves, shapes/dtypes/callables are static), so they pass through ``jit``,
 ``vmap``, ``lax.cond`` and ``shard_map`` exactly like plain arrays do.
 
-``estimate_2norm`` is the shared power-iteration σ_max estimator (formerly
+``estimate_2norm`` is the shared Golub–Kahan σ_max estimator (formerly
 private copies in the solver modules); it works on anything
 ``as_operator`` accepts.
 """
@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental.sparse import BCOO
+
+from ..kernels.common import matmul
 
 __all__ = [
     "LinearOperator",
@@ -131,16 +133,16 @@ class DenseOperator(LinearOperator):
         return self.A.dtype
 
     def matvec(self, x):
-        return self.A @ x
+        return matmul(self.A, x)
 
     def rmatvec(self, u):
-        return self.A.T @ u
+        return matmul(self.A.T, u)
 
     def matmat(self, X):
-        return self.A @ X
+        return matmul(self.A, X)
 
     def rmatmat(self, U):
-        return self.A.T @ U
+        return matmul(self.A.T, U)
 
     @property
     def materializable(self):
@@ -344,19 +346,37 @@ def ensure_dense(A, *, who: str = "this solver") -> jax.Array:
 
 
 def estimate_2norm(A, key: jax.Array, iters: int = 25) -> jax.Array:
-    """σ_max(A) by power iteration on AᵀA — the one shared 2-norm estimator.
+    """σ_max(A) by Golub–Kahan–Lanczos bidiagonalization — the one shared
+    2-norm estimator.
 
-    Accepts anything :func:`as_operator` does; only products with A are
-    used.  (Supersedes the private per-solver copies: SAA-SAS's fallback σ
-    and any future spectral-norm need route through here.)
+    ``iters`` steps of the bidiagonalization (one product with A and one
+    with Aᵀ each) give a (iters × iters) upper-bidiagonal B whose largest
+    singular value is a lower bound on σ_max(A) that converges far faster
+    than power iteration when σ₁ and σ₂ are close.  Accepts anything
+    :func:`as_operator` does; only products with A are used.  (Supersedes
+    the private per-solver copies: SAA-SAS's fallback σ and any future
+    spectral-norm need route through here.)
     """
     A = as_operator(A)
-    v = jax.random.normal(key, (A.shape[1],), A.dtype)
-    v = v / jnp.linalg.norm(v)
+    tiny = jnp.finfo(A.dtype).tiny
 
-    def body(_, v):
-        w = A.rmatvec(A.matvec(v))
-        return w / jnp.maximum(jnp.linalg.norm(w), jnp.finfo(A.dtype).tiny)
+    def unit(w):
+        nw = jnp.linalg.norm(w)
+        return w / jnp.maximum(nw, tiny), nw
 
-    v = lax.fori_loop(0, iters, body, v)
-    return jnp.linalg.norm(A.matvec(v))
+    v, _ = unit(jax.random.normal(key, (A.shape[1],), A.dtype))
+    u, alpha = unit(A.matvec(v))
+
+    def body(i, carry):
+        u, v, alpha, alphas, betas = carry
+        v, beta = unit(A.rmatvec(u) - alpha * v)
+        u, alpha = unit(A.matvec(v) - beta * u)
+        return u, v, alpha, alphas.at[i + 1].set(alpha), betas.at[i].set(beta)
+
+    alphas = jnp.zeros((iters,), A.dtype).at[0].set(alpha)
+    betas = jnp.zeros((max(iters - 1, 0),), A.dtype)
+    _, _, _, alphas, betas = lax.fori_loop(
+        0, iters - 1, body, (u, v, alpha, alphas, betas)
+    )
+    B = jnp.diag(alphas) + jnp.diag(betas, 1)
+    return jnp.linalg.svd(B, compute_uv=False)[0]

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..kernels.common import vdot
 from .result import SolveResult
 
 __all__ = ["lsqr", "lsqr_dense", "lsqr_operator", "LSQRResult"]
@@ -80,8 +81,8 @@ def lsqr(
     conlim: float = 1e8,
     iter_lim: int | None = None,
     steptol: float = 0.0,
-    vdot: Callable = jnp.vdot,
-    udot: Callable = jnp.vdot,
+    vdot: Callable = vdot,
+    udot: Callable = vdot,
     history: bool = False,
 ) -> SolveResult:
     """Minimize ‖Ax − b‖₂.

@@ -78,6 +78,7 @@ import jax.numpy as jnp
 from . import backend as backend_lib
 from . import certify as certify_lib
 from . import linop
+from ..kernels.common import matmul
 from ..obs import trace as obs_trace
 from .direct import qr_solve
 from .iterative import (
@@ -196,13 +197,13 @@ def select_method(
 @jax.jit
 def _direct_result(A, b):
     x = qr_solve(A, b)
-    r = b - A @ x
+    r = b - matmul(A, x)
     return SolveResult(
         x=x,
         istop=jnp.asarray(1, jnp.int32),
         itn=jnp.asarray(0, jnp.int32),
         rnorm=jnp.linalg.norm(r),
-        arnorm=jnp.linalg.norm(A.T @ r),
+        arnorm=jnp.linalg.norm(matmul(A.T, r)),
         used_fallback=jnp.asarray(False),
     )
 

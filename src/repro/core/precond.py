@@ -32,6 +32,7 @@ from jax.scipy.linalg import solve_triangular
 from . import backend as backend_lib
 from . import linop
 from . import sketch as sketch_lib
+from ..kernels.common import matmul
 from ..obs import trace as obs_trace
 
 __all__ = ["SketchedFactor", "default_sketch_size", "distortion"]
@@ -273,7 +274,7 @@ class SketchedFactor(NamedTuple):
         with obs_trace.span("factor.extend", extra=extra):
             op_new = op.extend_rows(key, extra)
             if B is None:
-                B = self.Q @ self.R
+                B = matmul(self.Q, self.R)
             B_new = op_new.extend_sketch(B, A, backend=backend)
             with obs_trace.span("factor.qr", shape=tuple(B_new.shape)):
                 factor = SketchedFactor.from_sketch(B_new)
@@ -335,7 +336,7 @@ class SketchedFactor(NamedTuple):
         system; using it is what makes the preconditioned solve start a
         constant factor from optimal rather than from zero.
         """
-        return self.Q.T @ c
+        return matmul(self.Q.T, c)
 
     def sketch_and_solve(self, c: jax.Array) -> jax.Array:
         """x̂ = R⁻¹ Qᵀ c — the plain sketch-and-solve estimate in x-space."""

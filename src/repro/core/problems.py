@@ -18,6 +18,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..kernels.common import matmul
+
 __all__ = ["generate", "Problem"]
 
 
@@ -30,7 +32,7 @@ class Problem(NamedTuple):
     beta: float
 
 
-@partial(jax.jit, static_argnames=("m", "n", "method"))
+@partial(jax.jit, static_argnames=("m", "n", "dtype", "method"))
 def generate(
     key: jax.Array,
     m: int,
@@ -56,7 +58,7 @@ def generate(
     V, _ = jnp.linalg.qr(jax.random.normal(k_v, (n, n), dtype), mode="reduced")
     log_k = jnp.log10(jnp.asarray(cond, dtype))
     sigma = jnp.logspace(0.0, -log_k, n, dtype=dtype)
-    A = (U1 * sigma) @ V.T
+    A = matmul(U1 * sigma, V.T)
 
     w = jax.random.normal(k_w, (n,), dtype)
     x = w / jnp.linalg.norm(w)
@@ -68,10 +70,10 @@ def generate(
     # Gaussian and x_true is the minimizer only up to O(β).
     g = jax.random.normal(k_z, (m,), dtype)
     if method == "haar":
-        v = g - U1 @ (U1.T @ g)
+        v = g - matmul(U1, matmul(U1.T, g))
     else:
         v = g
     r = beta * v / jnp.linalg.norm(v)
 
-    b = A @ x + r
+    b = matmul(A, x) + r
     return Problem(A=A, b=b, x_true=x, r_true=r, cond=cond, beta=beta)

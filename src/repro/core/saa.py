@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..kernels.common import matmul
 from . import linop
 from . import sketch as sketch_lib
 from .backend import resolve_backend_arg
@@ -48,6 +49,12 @@ from .precond import SketchedFactor, default_sketch_size
 from .result import SolveResult
 
 __all__ = ["saa_sas", "saa_sas_batch", "SAAResult", "default_sketch_size"]
+
+# LSQR stop codes (SciPy's) that mean the sketch did not embed the problem:
+# the whitened system's condition estimate passed conlim (3) or 1/eps (6).
+# The iteration limit (7) is left out: it also stops lanes that only
+# converge slowly, and a redraw would solve the whole batch twice.
+_EMBEDDING_FAILED = (3, 6)
 
 # Superseded by the unified result type.  The alias keeps attribute access
 # (res.x, res.itn, ...) working; field ORDER changed (arnorm inserted), so
@@ -63,7 +70,7 @@ def _solve_with_factor(
     z0 = factor.warm_start(c)
     if materialize_y:
         Y = factor.materialize_whitened(A)
-        mv, rmv = (lambda z: Y @ z), (lambda u: Y.T @ u)
+        mv, rmv = (lambda z: matmul(Y, z)), (lambda u: matmul(Y.T, u))
     else:
         mv = partial(factor.whiten_mv, A)
         rmv = partial(factor.whiten_rmv, A)
@@ -204,8 +211,12 @@ def saa_sas_batch(
       itself vmaps).  Returns x of shape (batch, n).
 
     The perturbation fallback of ``saa_sas`` is a per-problem control-flow
-    feature and is not taken here (``used_fallback`` is always False);
-    batch callers should re-solve non-converged lanes individually.  Note
+    feature and is not taken here.  In problem-batch mode a lane whose
+    LSQR stops on the condition limit (istop 3 or 6: the shared draw did
+    not embed its problem) is solved again under one independent draw
+    and reports ``used_fallback``; its ``istop`` is then the redraw's, so
+    a lane that still fails shows it.  The redraw solves the whole batch
+    again.  In multi-RHS mode ``used_fallback`` is always False.  Note
     vmap-of-while semantics: all lanes keep iterating until every lane's
     stopping test fires (extra LSQR iterations past convergence are benign —
     the whitened system's updates just stall at the numerical floor).
@@ -232,7 +243,7 @@ def saa_sas_batch(
 
         if materialize_y:
             Y = factor.materialize_whitened(A)
-            mv, rmv = (lambda z: Y @ z), (lambda u: Y.T @ u)
+            mv, rmv = (lambda z: matmul(Y, z)), (lambda u: matmul(Y.T, u))
         else:
             mv = partial(factor.whiten_mv, A)
             rmv = partial(factor.whiten_rmv, A)
@@ -253,17 +264,42 @@ def saa_sas_batch(
             )
         batch, m, n = A.shape
         s = sketch_size if sketch_size is not None else default_sketch_size(n, m)
-        op = sketch_lib.sample(sketch, key, s, m, dtype=A.dtype)
 
-        def solve_one(A_i, b_i):
-            factor = SketchedFactor.from_sketch(op.apply(A_i, backend=backend))
-            c = op.apply(b_i, backend=backend)
-            x, res = _solve_with_factor(
-                A_i, b_i, factor, c, materialize_y=materialize_y, **kw
-            )
-            return res._replace(x=x)
+        def solve_all(op_key):
+            op = sketch_lib.sample(sketch, op_key, s, m, dtype=A.dtype)
 
-        res = jax.vmap(solve_one)(A, b)
-        return res._replace(used_fallback=jnp.zeros(batch, bool))
+            def solve_one(A_i, b_i):
+                factor = SketchedFactor.from_sketch(
+                    op.apply(A_i, backend=backend)
+                )
+                c = op.apply(b_i, backend=backend)
+                x, res = _solve_with_factor(
+                    A_i, b_i, factor, c, materialize_y=materialize_y, **kw
+                )
+                return res._replace(x=x)
+
+            return jax.vmap(solve_one)(A, b)
+
+        res = solve_all(key)
+        # A lane whose whitened LSQR stopped on the condition limit was
+        # not embedded by the shared draw (a sparse sketch can
+        # hash two of a padded problem's identity rows into one bucket and
+        # lose rank).  Those lanes are solved again under one independent
+        # draw, and report used_fallback.
+        failed = jnp.isin(res.istop, jnp.asarray(_EMBEDDING_FAILED))
+
+        def redraw(_):
+            res2 = solve_all(jax.random.fold_in(key, 1))
+
+            def pick(first, second):
+                lane = failed.reshape(failed.shape + (1,) * (first.ndim - 1))
+                return jnp.where(lane, second, first)
+
+            return jax.tree.map(pick, res, res2)._replace(used_fallback=failed)
+
+        def keep(_):
+            return res._replace(used_fallback=jnp.zeros(batch, bool))
+
+        return lax.cond(jnp.any(failed), redraw, keep, None)
 
     raise ValueError(f"A must be (m, n) or (batch, m, n), got shape {A.shape}")

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from . import backend as backend_lib
+from ..kernels.common import matmul
 
 __all__ = [
     "sample",
@@ -70,11 +71,13 @@ def _kernels():
     return kernels
 
 
-def _tuned_blocks(kind: str, A, d: int) -> dict:
+def _tuned_blocks(kind: str, A, d: int, rb) -> dict:
     """Autotuned block kwargs for a pallas dispatch (``{}`` = kernel defaults)."""
     m = A.shape[0]
     n = A.shape[1] if A.ndim > 1 else 1
-    return backend_lib.kernel_blocks(kind, m, n, d, A.dtype)
+    return backend_lib.kernel_blocks(
+        kind, m, n, d, A.dtype, interpret=rb.interpret
+    )
 
 
 def fwht(x: jax.Array, axis: int = 0) -> jax.Array:
@@ -287,13 +290,13 @@ class GaussianSketch(_OperatorApply):
     def apply(self, A, *, backend: str = "auto"):
         rb = backend_lib.resolve(backend)
         if rb.use_pallas:
-            blocks = _tuned_blocks("gaussian", A, self.d)
+            blocks = _tuned_blocks("gaussian", A, self.d, rb)
             return _kernels().fused_gaussian_sketch(
                 A, self.key, self.d, interpret=rb.interpret, **blocks
             )
         A2, vec = _as_2d(A)
         S = self.S if self.S is not None else self.as_dense().astype(A2.dtype)
-        return _maybe_squeeze(S @ A2, vec)
+        return _maybe_squeeze(matmul(S, A2), vec)
 
     def apply_rows(self, tile, row_offset: int, *, backend: str = "auto"):
         del backend  # one (d, t) × (t, n) block product either way
@@ -305,7 +308,7 @@ class GaussianSketch(_OperatorApply):
             St = self._gen_cols(
                 self.key, self.d, row_offset + jnp.arange(t), tile2.dtype
             )
-        return St.astype(tile2.dtype) @ tile2
+        return matmul(St.astype(tile2.dtype), tile2)
 
     def restrict_cols(self, idx):
         S = self._cols(idx, jnp.float64)
@@ -342,18 +345,21 @@ class UniformDenseSketch(_OperatorApply):
     def apply(self, A, *, backend: str = "auto"):
         rb = backend_lib.resolve(backend)
         if rb.use_pallas:
-            blocks = _tuned_blocks("sketch_matmul", A, self.d)
+            blocks = _tuned_blocks("sketch_matmul", A, self.d, rb)
+            # S in A's dtype, as the fused path and the Gaussian kernel
+            # do: a bf16 A (precision="mixed") keeps the single-pass MXU
+            # contraction its tiles were sized for.
             return _kernels().sketch_matmul(
-                self.S, A, interpret=rb.interpret, **blocks
+                self.S.astype(A.dtype), A, interpret=rb.interpret, **blocks
             )
         A2, vec = _as_2d(A)
-        return _maybe_squeeze(self.S @ A2, vec)
+        return _maybe_squeeze(matmul(self.S, A2), vec)
 
     def apply_rows(self, tile, row_offset: int, *, backend: str = "auto"):
         del backend
         tile2, _ = _as_2d(tile)
         St = self.S[:, row_offset : row_offset + tile2.shape[0]]
-        return St.astype(tile2.dtype) @ tile2
+        return matmul(St.astype(tile2.dtype), tile2)
 
     def restrict_cols(self, idx):
         return UniformDenseSketch(S=self.S[:, idx], d=self.d, m=len(idx))
@@ -395,7 +401,7 @@ class SRHTSketch(_OperatorApply):
     def apply(self, A, *, backend: str = "auto"):
         rb = backend_lib.resolve(backend)
         if rb.use_pallas:
-            blocks = _tuned_blocks("srht", A, self.d)
+            blocks = _tuned_blocks("srht", A, self.d, rb)
             return _kernels().srht_apply(
                 A, self.signs, self.rows, self.d, interpret=rb.interpret, **blocks
             )
@@ -475,7 +481,7 @@ class CountSketch(_OperatorApply):
     def apply(self, A, *, backend: str = "auto"):
         rb = backend_lib.resolve(backend)
         if rb.use_pallas:
-            blocks = _tuned_blocks("countsketch", A, self.d)
+            blocks = _tuned_blocks("countsketch", A, self.d, rb)
             return _kernels().countsketch_apply(
                 A, self.buckets, self.signs, self.d, interpret=rb.interpret, **blocks
             )

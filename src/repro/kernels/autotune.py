@@ -11,12 +11,16 @@ blocks along the revisited axes trade VMEM footprint for HBM traffic.
 Candidates that overflow the VMEM budget are discarded before costing.
 
 Winners are cached in-repo at ``src/repro/kernels/autotune_cache.json``,
-keyed ``"{kind}|m={m}|n={n}|d={d}|{dtype}|{device}"``.  ``best_blocks`` is
-the runtime entry point — exact cache hits return the committed winner,
-misses fall back to the cost model on the fly (memoized per process).  The
-backend policy (``repro.core.backend.kernel_blocks``) consults it for every
-kernel dispatch; set ``REPRO_AUTOTUNE=0`` to force the kernels' hand-tuned
-defaults.
+keyed ``"{kind}|m={m}|n={n}|d={d}|{dtype}|{device}"`` with ``device`` the
+``jax.Device.device_kind`` string as JAX reports it (``"TPU v5 lite"`` on a
+v5e).  The committed winners were chosen by the cost model, not measured.
+``best_blocks`` is the runtime entry point — exact cache hits return the
+committed winner, misses fall back to the cost model on the fly (memoized
+per process).  The cost model reads the device's peaks from
+``repro.launch.mesh.peaks`` and raises for a device it has no table for.
+The backend policy (``repro.core.backend.kernel_blocks``) consults it for
+every kernel dispatch; set ``REPRO_AUTOTUNE=0`` to force the kernels'
+hand-tuned defaults.
 
 Regenerate the cache after kernel/geometry changes::
 
@@ -32,6 +36,8 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+
+from ..launch.mesh import peaks
 
 __all__ = ["best_blocks", "predict_cost", "CACHE_PATH", "KINDS"]
 
@@ -54,24 +60,22 @@ KINDS = {
 }
 _ALIASES = {"uniform_dense": "sketch_matmul", "clarkson_woodruff": "countsketch"}
 
-# VMEM working-set budget per grid step.  v5e has ~16 MiB/core; half of it
-# keeps double-buffered pipelines honest.
-VMEM_BUDGET = 8 * 1024 * 1024
+# VMEM budget per grid step for the model's working set below: the
+# double-buffered input and output tiles plus the large in-kernel tiles.
+# Mosaic's scoped-VMEM limit on v5e is 16 MiB; the margin covers what the
+# model does not count.  Checked against compiles for a described v5e.
+VMEM_BUDGET = 12 * 1024 * 1024
+# 32-bit tiles contract at full fp32 precision (``kernels.common.mxu_dot``),
+# which Mosaic splits into several bf16 passes with VMEM temporaries the
+# model above does not see.  Tiles of 32-bit kernels stay under these
+# element counts (A tile, S or one-hot tile, output tile), the envelope
+# that compiled for a described v5e.
+_FP32_TILE_ELEMS = (1 << 18, 1 << 19, 1 << 18)
 _STEP_OVERHEAD_S = 5e-7  # per-grid-step launch cost; penalizes tiny blocks
 
 _BLOCK_M = (128, 256, 512, 1024, 2048)
 _BLOCK_D = (128, 256, 512, 1024)
 _BLOCK_N = (128, 256, 512)
-
-
-def _hw():
-    """Roofline constants — shared with benchmarks via repro.launch.mesh."""
-    try:
-        from ..launch.mesh import HW
-
-        return HW
-    except Exception:  # pragma: no cover - mesh module should always import
-        return {"peak_flops_bf16": 197e12, "hbm_bw": 819e9}
 
 
 def _dtype_bytes(dtype) -> int:
@@ -82,8 +86,8 @@ def _cdiv(a: int, b: int) -> int:
     return -(-a // b)
 
 
-def _peak_flops(dtype) -> float:
-    peak = float(_hw().get("peak_flops_bf16", 197e12))
+def _peak_flops(dtype, hw: dict) -> float:
+    peak = float(hw["peak_flops_bf16"])
     # MXU fp32 runs at roughly half the bf16 rate; fp64 emulation far slower.
     itemsize = _dtype_bytes(dtype)
     if itemsize <= 2:
@@ -93,12 +97,16 @@ def _peak_flops(dtype) -> float:
     return peak / 8
 
 
-def predict_cost(kind: str, m: int, n: int, d: int, dtype, blocks: dict) -> float:
-    """Roofline-predicted seconds for one kernel launch with these blocks.
+def predict_cost(
+    kind: str, m: int, n: int, d: int, dtype, blocks: dict, device: str
+) -> float:
+    """Roofline-predicted seconds for one kernel launch with these blocks
+    on ``device`` (a ``device_kind`` with a peak table).
 
     Returns ``inf`` for configs whose VMEM working set exceeds the budget,
     so infeasible candidates lose every comparison.
     """
+    hw = peaks(device)
     kind = _ALIASES.get(kind, kind)
     b = _dtype_bytes(dtype)
     acc_b = max(b, 4)  # half inputs accumulate in f32
@@ -116,18 +124,21 @@ def predict_cost(kind: str, m: int, n: int, d: int, dtype, blocks: dict) -> floa
         # bucket/sign columns re-read per (d, n) block.
         traffic = m * n * b * d_blocks + m * (4 + b) * d_blocks * n_blocks
         traffic += d * n * b
-        vmem = (bm * bn + bm * bd + bd * bn) * b + 2 * bm * 4
+        # A tile, bucket and sign rows (sublane-padded to 8), output tile
+        vmem = 2 * (bm * bn * b + 2 * 8 * bm * 4 + bd * bn * acc_b)
         steps = m_blocks * d_blocks * n_blocks
     elif kind == "sketch_matmul":
         traffic = d * m * b * n_blocks + m * n * b * d_blocks + d * n * b
-        vmem = (bd * bm + bm * bn + bd * bn) * b
+        # S and A tiles, output tile, and the f32 dot result
+        vmem = 2 * ((bd * bm + bm * bn) * b + bd * bn * acc_b) + bd * bn * 4
         steps = m_blocks * d_blocks * n_blocks
     elif kind == "gaussian":
         # S is generated in-kernel: no S traffic, but the threefry+Box-Muller
         # pipeline costs ~32 scalar ops per S element, re-done per n-block.
         traffic = m * n * b * d_blocks + d * n * b
         flops += 32.0 * d * m * n_blocks
-        vmem = (bd * bm + bm * bn + bd * bn) * b
+        # A tile, output tile, and the generated S tile (f32 and its cast)
+        vmem = 2 * (bm * bn * b + bd * bn * acc_b) + 2 * bd * bm * 4
         steps = m_blocks * d_blocks * n_blocks
     elif kind == "srht":
         # two-stage FWHT over m_pad rows: log2(m) butterfly sweeps, each a
@@ -150,8 +161,16 @@ def predict_cost(kind: str, m: int, n: int, d: int, dtype, blocks: dict) -> floa
 
     if vmem > VMEM_BUDGET:
         return float("inf")
-    hbm_bw = float(_hw().get("hbm_bw", 819e9))
-    return max(traffic / hbm_bw, flops / _peak_flops(dtype)) + steps * _STEP_OVERHEAD_S
+    if b >= 4 and kind != "srht":
+        tile_n = n_p if kind == "tsqr" else bn
+        a_cap, s_cap, o_cap = _FP32_TILE_ELEMS
+        if bm * tile_n > a_cap or bd * bm > s_cap or bd * tile_n > o_cap:
+            return float("inf")
+    hbm_bw = float(hw["hbm_bw"])
+    return (
+        max(traffic / hbm_bw, flops / _peak_flops(dtype, hw))
+        + steps * _STEP_OVERHEAD_S
+    )
 
 
 def _candidates(kind: str, m: int, n: int, d: int):
@@ -175,10 +194,7 @@ def _candidates(kind: str, m: int, n: int, d: int):
 
 
 def _device_kind() -> str:
-    try:
-        return jax.devices()[0].device_kind.replace(" ", "_")
-    except Exception:  # pragma: no cover - no runtime attached
-        return "unknown"
+    return jax.devices()[0].device_kind
 
 
 def _key(kind: str, m: int, n: int, d: int, dtype, device: str) -> str:
@@ -197,14 +213,15 @@ def _load_cache() -> dict:
 
 
 @functools.lru_cache(maxsize=4096)
-def _model_best(kind: str, m: int, n: int, d: int, dtype_name: str) -> tuple:
+def _model_best(
+    kind: str, m: int, n: int, d: int, dtype_name: str, device: str
+) -> tuple:
     best, best_cost = None, float("inf")
     for cand in _candidates(kind, m, n, d):
-        c = predict_cost(kind, m, n, d, dtype_name, cand)
+        c = predict_cost(kind, m, n, d, dtype_name, cand, device)
         if c < best_cost:
             best, best_cost = cand, c
-    # every family has at least one VMEM-feasible candidate at these sizes,
-    # but fall back to kernel defaults ({}), never crash, if the model says no
+    # with no VMEM-feasible candidate the kernel's own defaults ({}) run
     return tuple(sorted((best or {}).items()))
 
 
@@ -226,7 +243,7 @@ def best_blocks(
     hit = _load_cache().get(key)
     if hit is not None:
         return {k: v for k, v in hit.items() if k in KINDS[kind]}
-    blocks = dict(_model_best(kind, m, n, d, jnp.dtype(dtype).name))
+    blocks = dict(_model_best(kind, m, n, d, jnp.dtype(dtype).name, device))
     if key not in _MISS_WARNED:
         _MISS_WARNED.add(key)
         _log.warning(
@@ -258,7 +275,7 @@ def write_cache(device: str | None = None, path: Path | None = None) -> dict:
         for m, n, d in _sweep_shapes():
             for dtype in ("float32", "bfloat16"):
                 entries[_key(kind, m, n, d, dtype, device)] = dict(
-                    _model_best(kind, m, n, d, dtype)
+                    _model_best(kind, m, n, d, dtype, device)
                 )
     payload = {"schema": CACHE_SCHEMA, "entries": entries}
     out = path or CACHE_PATH

@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import cdiv, pad_to
+from ..common import cdiv, mosaic_context, pad_to, resolve_interpret
 from .kernel import countsketch_kernel
 
 __all__ = ["countsketch_apply"]
@@ -34,10 +34,7 @@ def countsketch_apply(
     ``interpret=None`` resolves via ``repro.core.backend.default_interpret``
     (real Mosaic on TPU, interpret mode elsewhere).
     """
-    if interpret is None:
-        from ...core.backend import default_interpret
-
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret, A)
     vec = A.ndim == 1
     if vec:
         A = A[:, None]
@@ -48,26 +45,30 @@ def countsketch_apply(
     bd = min(block_d, max(8, d))
     bn = min(block_n, max(128, n)) if n >= 128 else 128
 
-    A_p = pad_to(A, (bm, bn))
-    # Padded rows get sign 0 -> contribute nothing (bucket 0 is fine).
-    h_p = pad_to(buckets.astype(jnp.int32)[:, None], (bm, 1))
-    s_p = pad_to(signs.astype(A.dtype)[:, None], (bm, 1))
+    # A is not padded: a partial last tile is masked in the kernel.  Only
+    # inputs narrower than one tile (vectors, m < 8) are padded.
+    A_p = pad_to(A, (bm if m < bm else 1, 128 if n < 128 else 1))
     m_p, n_p = A_p.shape
+    # Padded rows get sign 0 -> contribute nothing (bucket 0 is fine).
+    h_p = pad_to(buckets.astype(jnp.int32)[None, :], (1, bm))
+    s_p = pad_to(signs.astype(jnp.float32)[None, :], (1, bm))
     d_p = cdiv(d, bd) * bd
+    kernel = partial(countsketch_kernel, m=m_p if m_p % bm else None)
 
-    grid = (n_p // bn, d_p // bd, m_p // bm)
-    out = pl.pallas_call(
-        countsketch_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, 1), lambda ni, di, mi: (mi, 0)),
-            pl.BlockSpec((bm, 1), lambda ni, di, mi: (mi, 0)),
-            pl.BlockSpec((bm, bn), lambda ni, di, mi: (mi, ni)),
-        ],
-        out_specs=pl.BlockSpec((bd, bn), lambda ni, di, mi: (di, ni)),
-        out_shape=jax.ShapeDtypeStruct((d_p, n_p), acc_dtype),
-        interpret=interpret,
-    )(h_p, s_p, A_p)
+    grid = (cdiv(n_p, bn), d_p // bd, cdiv(m_p, bm))
+    with mosaic_context(interpret):
+        out = pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, bm), lambda ni, di, mi: (0, mi)),
+                pl.BlockSpec((1, bm), lambda ni, di, mi: (0, mi)),
+                pl.BlockSpec((bm, bn), lambda ni, di, mi: (mi, ni)),
+            ],
+            out_specs=pl.BlockSpec((bd, bn), lambda ni, di, mi: (di, ni)),
+            out_shape=jax.ShapeDtypeStruct((d_p, n_p), acc_dtype),
+            interpret=interpret,
+        )(h_p, s_p, A_p)
     # half-precision inputs keep the f32 accumulator dtype (mixed-precision
     # contract: bf16 data, >= f32 sketch output for the QR/refinement stages)
     out = out[:d, :n]

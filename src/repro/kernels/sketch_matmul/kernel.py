@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import bits_to_gaussian, threefry2x32
+from ..common import bits_to_gaussian, mxu_dot, threefry2x32
 
 
 def matmul_kernel(s_ref, a_ref, o_ref):
@@ -32,9 +32,7 @@ def matmul_kernel(s_ref, a_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += jnp.dot(
-        s_ref[...], a_ref[...], preferred_element_type=o_ref.dtype
-    )
+    o_ref[...] += mxu_dot(s_ref[...], a_ref[...], o_ref.dtype)
 
 
 def fused_gaussian_kernel(k0_ref, k1_ref, scale_ref, a_ref, o_ref):
@@ -64,6 +62,4 @@ def fused_gaussian_kernel(k0_ref, k1_ref, scale_ref, a_ref, o_ref):
     b0, b1 = threefry2x32(k0_ref[0, 0], k1_ref[0, 0], rows, cols)
     s_blk = bits_to_gaussian(b0, b1, jnp.float32) * scale_ref[0, 0]
 
-    o_ref[...] += jnp.dot(
-        s_blk.astype(a.dtype), a, preferred_element_type=o_ref.dtype
-    )
+    o_ref[...] += mxu_dot(s_blk.astype(a.dtype), a, o_ref.dtype)

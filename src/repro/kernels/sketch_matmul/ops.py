@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import cdiv, key_to_u32, pad_to
+from ..common import cdiv, key_to_u32, mosaic_context, pad_to, resolve_interpret
 from .kernel import fused_gaussian_kernel, matmul_kernel
 
 __all__ = ["sketch_matmul", "fused_gaussian_sketch"]
@@ -29,10 +29,7 @@ def sketch_matmul(
 
     ``interpret=None`` resolves via ``repro.core.backend.default_interpret``.
     """
-    if interpret is None:
-        from ...core.backend import default_interpret
-
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret, S, A)
     vec = A.ndim == 1
     A2 = A[:, None] if vec else A
     d, m = S.shape
@@ -48,17 +45,18 @@ def sketch_matmul(
     d_p, m_p = S_p.shape
     n_p = A_p.shape[1]
 
-    out = pl.pallas_call(
-        matmul_kernel,
-        grid=(d_p // bd, n_p // bn, m_p // bm),
-        in_specs=[
-            pl.BlockSpec((bd, bm), lambda di, ni, mi: (di, mi)),
-            pl.BlockSpec((bm, bn), lambda di, ni, mi: (mi, ni)),
-        ],
-        out_specs=pl.BlockSpec((bd, bn), lambda di, ni, mi: (di, ni)),
-        out_shape=jax.ShapeDtypeStruct((d_p, n_p), acc),
-        interpret=interpret,
-    )(S_p, A_p)
+    with mosaic_context(interpret):
+        out = pl.pallas_call(
+            matmul_kernel,
+            grid=(d_p // bd, n_p // bn, m_p // bm),
+            in_specs=[
+                pl.BlockSpec((bd, bm), lambda di, ni, mi: (di, mi)),
+                pl.BlockSpec((bm, bn), lambda di, ni, mi: (mi, ni)),
+            ],
+            out_specs=pl.BlockSpec((bd, bn), lambda di, ni, mi: (di, ni)),
+            out_shape=jax.ShapeDtypeStruct((d_p, n_p), acc),
+            interpret=interpret,
+        )(S_p, A_p)
     # half-precision inputs keep the f32 accumulator dtype (mixed-precision
     # contract: bf16 data, >= f32 sketch output for the QR/refinement stages)
     out = out[:d, :n]
@@ -87,10 +85,7 @@ def fused_gaussian_sketch(
     draws its S from the same stream, so this kernel IS its pallas backend).
     ``interpret=None`` resolves via ``repro.core.backend.default_interpret``.
     """
-    if interpret is None:
-        from ...core.backend import default_interpret
-
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret, A)
     vec = A.ndim == 1
     A2 = A[:, None] if vec else A
     m, n = A2.shape
@@ -113,18 +108,19 @@ def fused_gaussian_sketch(
     k1 = k1.reshape(1, 1)
     scale_arr = jnp.asarray(scale, jnp.float32).reshape(1, 1)
 
-    out = pl.pallas_call(
-        fused_gaussian_kernel,
-        grid=(d_p // bd, n_p // bn, m_p // bm),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda di, ni, mi: (0, 0)),
-            pl.BlockSpec((1, 1), lambda di, ni, mi: (0, 0)),
-            pl.BlockSpec((1, 1), lambda di, ni, mi: (0, 0)),
-            pl.BlockSpec((bm, bn), lambda di, ni, mi: (mi, ni)),
-        ],
-        out_specs=pl.BlockSpec((bd, bn), lambda di, ni, mi: (di, ni)),
-        out_shape=jax.ShapeDtypeStruct((d_p, n_p), acc),
-        interpret=interpret,
-    )(k0, k1, scale_arr, A_p)
+    with mosaic_context(interpret):
+        out = pl.pallas_call(
+            fused_gaussian_kernel,
+            grid=(d_p // bd, n_p // bn, m_p // bm),
+            in_specs=[
+                pl.BlockSpec((1, 1), lambda di, ni, mi: (0, 0)),
+                pl.BlockSpec((1, 1), lambda di, ni, mi: (0, 0)),
+                pl.BlockSpec((1, 1), lambda di, ni, mi: (0, 0)),
+                pl.BlockSpec((bm, bn), lambda di, ni, mi: (mi, ni)),
+            ],
+            out_specs=pl.BlockSpec((bd, bn), lambda di, ni, mi: (di, ni)),
+            out_shape=jax.ShapeDtypeStruct((d_p, n_p), acc),
+            interpret=interpret,
+        )(k0, k1, scale_arr, A_p)
     out = out[:d, :n]  # keep the f32 accumulator dtype for half inputs
     return out[:, 0] if vec else out

@@ -4,7 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..common import bits_to_gaussian, key_to_u32, threefry2x32
+from ..common import bits_to_gaussian, key_to_u32, matmul, threefry2x32
 
 __all__ = [
     "sketch_matmul_ref",
@@ -15,7 +15,7 @@ __all__ = [
 
 
 def sketch_matmul_ref(S: jax.Array, A: jax.Array) -> jax.Array:
-    return S @ A
+    return matmul(S, A)
 
 
 def gaussian_matrix_ref(
@@ -58,5 +58,5 @@ def fused_gaussian_ref(A: jax.Array, key: jax.Array, d: int, scale=None):
     if scale is None:
         scale = 1.0 / float(d) ** 0.5
     S = gaussian_matrix_ref(key, d, m, A2.dtype) * jnp.asarray(scale, A2.dtype)
-    out = S @ A2
+    out = matmul(S, A2)
     return out[:, 0] if vec else out

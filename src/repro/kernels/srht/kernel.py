@@ -1,37 +1,28 @@
-"""Blocked Walsh–Hadamard transform as TPU Pallas kernels.
+"""Blocked Walsh–Hadamard transform as a TPU Pallas kernel.
 
 The classic FHT butterfly has stride-2^k access patterns — hostile to VMEM
 tiling.  On TPU we instead use the Kronecker factorization
 
-    H_{r·c} = (H_r ⊗ I_c) · (I_r ⊗ H_c)
+    H_{c₁·c₂·…·c_k} = H_{c₁} ⊗ H_{c₂} ⊗ … ⊗ H_{c_k}
 
 (valid for Sylvester Hadamard matrices, H_{2^p} = H_2^{⊗p}), which turns the
-transform into two dense ±1 **matmuls** over VMEM-resident tiles — exactly
-what the MXU wants:
+transform into k dense ±1 **matmuls**, one per axis of the (c₁, …, c_k, n)
+view of x — exactly what the MXU wants.  Each stage views x as (a, c, b),
+with c the axis being transformed, and computes
 
-  stage 1 (`block_hadamard_kernel`):  y[k]  = H_c · x[k]       (within block)
-  stage 2 (`cross_hadamard_kernel`):  z[k'] = Σ_k H_r[k',k] y[k] (across blocks)
+    y[i] = H_c · x[i]        for every i < a        (``hadamard_axis_kernel``)
 
-Flop cost rises from O(m log m) adds to O(m·(r+c)) = O(m·√m) MACs, but both
-stages stream each element exactly once from HBM, and for the SRHT's m up to
-2^20 the MXU matmul path is faster than a strided butterfly emulation on TPU.
+over (c, bk) column tiles of b.  Flop cost rises from O(m log m) adds to
+O(m·Σcᵢ) MACs, but every stage streams each element once from HBM.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from ..common import mxu_dot
 
 
-def block_hadamard_kernel(h_ref, x_ref, o_ref):
-    """x block (1, c, bn);  h (c, c);  o = h @ x."""
-    o_ref[0, ...] = jnp.dot(
-        h_ref[...], x_ref[0, ...], preferred_element_type=o_ref.dtype
-    )
-
-
-def cross_hadamard_kernel(h_ref, x_ref, o_ref):
-    """x block (r, bs, bn);  h (r, r);  o[k'] = Σ_k h[k',k] x[k]."""
-    r, bs, bn = x_ref.shape
-    flat = x_ref[...].reshape(r, bs * bn)
-    out = jnp.dot(h_ref[...], flat, preferred_element_type=o_ref.dtype)
-    o_ref[...] = out.reshape(r, bs, bn)
+def hadamard_axis_kernel(h_ref, x_ref, o_ref):
+    """x block (1, c, bk);  h (c, c);  o = h @ x, accumulated in ≥ f32."""
+    acc = jnp.promote_types(o_ref.dtype, jnp.float32)
+    o_ref[0, ...] = mxu_dot(h_ref[...], x_ref[0, ...], acc).astype(o_ref.dtype)

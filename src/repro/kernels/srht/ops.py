@@ -7,8 +7,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import cdiv, pad_to
-from .kernel import block_hadamard_kernel, cross_hadamard_kernel
+from ..common import cdiv, mosaic_context, pad_to, resolve_interpret
+from .kernel import hadamard_axis_kernel
 
 __all__ = ["hadamard_transform", "srht_apply", "hadamard_matrix"]
 
@@ -20,79 +20,91 @@ def hadamard_matrix(k: int, dtype=jnp.float32) -> jax.Array:
     return (1 - 2 * par.astype(jnp.int32)).astype(dtype)
 
 
-def _split_pow2(m: int) -> tuple[int, int]:
-    """m = r * c, both powers of two, c as large as possible ≤ 1024."""
+# Largest Hadamard factor one stage multiplies by: H_c stays resident in
+# VMEM (double-buffered), so c = 1024 costs 8 MiB in f32.
+_MAX_FACTOR_BITS = 10
+# Scoped-VMEM budget of one stage (the v5e limit is 16 MiB): the resident
+# H_c, double-buffered input and output tiles, and the f32 dot result.
+_VMEM_BUDGET = 12 * 1024 * 1024
+
+
+def _factor_bits(m: int) -> tuple[int, ...]:
+    """log2 of the Kronecker factors of H_m: as few stages as the 2^10 cap
+    allows, with the bits spread evenly (fewer MACs than one big factor)."""
     p = m.bit_length() - 1
-    c_bits = min(p, 10)
-    return m >> c_bits, 1 << c_bits  # (r, c)
+    k = -(-p // _MAX_FACTOR_BITS)
+    return tuple(p // k + (i < p % k) for i in range(k))
+
+
+def _lane_block(c: int, b: int, itemsize: int, cap: int) -> int:
+    """Column tile of one stage: the whole b when it fits, else the largest
+    multiple of 128 (≤ cap) whose working set fits ``_VMEM_BUDGET``."""
+    resident = 2 * c * c * itemsize
+    per_col = c * (4 * itemsize + 4)
+    fit = (_VMEM_BUDGET - resident) // per_col // 128 * 128
+    bk = max(128, min(cap // 128 * 128, fit))
+    if resident + per_col * bk > _VMEM_BUDGET:
+        raise ValueError(
+            f"Hadamard factor {c} does not fit the VMEM budget in "
+            f"{itemsize}-byte elements"
+        )
+    return b if b <= bk else bk
+
+
+def _hadamard_stage(x3, h, block_n, interpret, alias):
+    """H_c along axis 1 of the (a, c, b) array x3."""
+    a, c, b = x3.shape
+    # float64 only runs in interpret mode; tile it as f32 would be on the chip.
+    bk = _lane_block(c, b, min(x3.dtype.itemsize, 4), block_n)
+    with mosaic_context(interpret):
+        return pl.pallas_call(
+            hadamard_axis_kernel,
+            grid=(a, cdiv(b, bk)),
+            in_specs=[
+                pl.BlockSpec((c, c), lambda i, j: (0, 0)),
+                pl.BlockSpec((1, c, bk), lambda i, j: (i, 0, j)),
+            ],
+            out_specs=pl.BlockSpec((1, c, bk), lambda i, j: (i, 0, j)),
+            out_shape=jax.ShapeDtypeStruct(x3.shape, x3.dtype),
+            # Each tile is read and written in place, so an intermediate
+            # stage's buffer can be reused for its output.
+            input_output_aliases={1: 0} if alias else {},
+            interpret=interpret,
+        )(h, x3)
 
 
 @partial(jax.jit, static_argnames=("block_n", "interpret"))
 def hadamard_transform(
-    x: jax.Array, *, block_n: int = 256, interpret: bool | None = None
+    x: jax.Array, *, block_n: int = 512, interpret: bool | None = None
 ) -> jax.Array:
     """Unnormalized Walsh–Hadamard transform along axis 0 (m a power of 2).
 
+    Half-precision inputs accumulate in f32 and are rounded back after each
+    stage.  ``block_n`` caps the column tile; it shrinks further to fit VMEM.
     ``interpret=None`` resolves via ``repro.core.backend.default_interpret``.
     """
-    if interpret is None:
-        from ...core.backend import default_interpret
-
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret, x)
     vec = x.ndim == 1
     if vec:
         x = x[:, None]
     m, n = x.shape
     if m & (m - 1):
         raise ValueError(f"m must be a power of two, got {m}")
-    dtype = x.dtype
-    r, c = _split_pow2(m)
-
-    bn = min(block_n, max(128, n)) if n >= 128 else 128
-    x_p = pad_to(x, (1, bn))
-    n_p = x_p.shape[1]
-    nb = n_p // bn
-
-    # ---- stage 1: (I_r ⊗ H_c) ----
-    h_c = hadamard_matrix(c, dtype)
-    y = pl.pallas_call(
-        block_hadamard_kernel,
-        grid=(r, nb),
-        in_specs=[
-            pl.BlockSpec((c, c), lambda k, ni: (0, 0)),
-            pl.BlockSpec((1, c, bn), lambda k, ni: (k, 0, ni)),
-        ],
-        out_specs=pl.BlockSpec((1, c, bn), lambda k, ni: (k, 0, ni)),
-        out_shape=jax.ShapeDtypeStruct((r, c, n_p), dtype),
-        interpret=interpret,
-    )(h_c, x_p.reshape(r, c, n_p))
-
-    if r == 1:
-        out = y.reshape(m, n_p)
-    else:
-        # ---- stage 2: (H_r ⊗ I_c) ----
-        h_r = hadamard_matrix(r, dtype)
-        # Sublane block of the c axis, sized so the (r, bs, bn) VMEM tile
-        # stays ≤ 2 MiB (H_r itself takes r²·4 bytes, up to 4 MiB at r=1024).
-        bs = max(8, (2**21 // (r * bn * 4)) // 8 * 8)
-        bs = min(bs, c)
-        while c % bs:
-            bs //= 2
-        bs = max(bs, 1)
-        z = pl.pallas_call(
-            cross_hadamard_kernel,
-            grid=(c // bs, nb),
-            in_specs=[
-                pl.BlockSpec((r, r), lambda si, ni: (0, 0)),
-                pl.BlockSpec((r, bs, bn), lambda si, ni: (0, si, ni)),
-            ],
-            out_specs=pl.BlockSpec((r, bs, bn), lambda si, ni: (0, si, ni)),
-            out_shape=jax.ShapeDtypeStruct((r, c, n_p), dtype),
-            interpret=interpret,
-        )(h_r, y)
-        out = z.reshape(m, n_p)
-
-    out = out[:, :n]
+    # Columns are independent, so a partial last column tile needs no
+    # padding; only narrow inputs are widened to one full lane tile.
+    y = pad_to(x, (1, 128)) if n < 128 else x
+    n_p = y.shape[1]
+    rest = m * n_p
+    before = 1
+    for i, bits in enumerate(_factor_bits(m)):
+        c = 1 << bits
+        rest //= c
+        h = hadamard_matrix(c, y.dtype)
+        y = _hadamard_stage(
+            y.reshape(before, c, rest), h, block_n, interpret, alias=i > 0
+        )
+        before *= c
+    out = y.reshape(m, n_p)[:, :n]
     return out[:, 0] if vec else out
 
 
@@ -103,7 +115,7 @@ def srht_apply(
     rows: jax.Array,
     d: int,
     *,
-    block_n: int = 256,
+    block_n: int = 512,
     interpret: bool | None = None,
 ) -> jax.Array:
     """SRHT sketch S·A = (1/√d) · P · H · D · A.
@@ -113,10 +125,7 @@ def srht_apply(
     D-scaling and P-gather stay in XLA (memory-bound, fusable).
     ``interpret=None`` resolves via ``repro.core.backend.default_interpret``.
     """
-    if interpret is None:
-        from ...core.backend import default_interpret
-
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret, A)
     vec = A.ndim == 1
     A2 = A[:, None] if vec else A
     m, n = A2.shape

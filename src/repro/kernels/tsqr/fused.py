@@ -32,7 +32,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import cdiv, key_to_u32, pad_to
+from ..common import (
+    cdiv, key_to_u32, matmul, mosaic_context, pad_to, resolve_interpret,
+)
 from .kernel import (
     countsketch_gram_kernel,
     make_gaussian_gram_kernel,
@@ -53,20 +55,21 @@ def _fused_call(kernel, inputs, in_specs, d, n, bd, interpret, acc):
     d_p = cdiv(d, bd) * bd
     m_blocks = in_specs.pop("m_blocks")
     grid = (d_p // bd, m_blocks)
-    B, G = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs.pop("specs"),
-        out_specs=[
-            pl.BlockSpec((bd, n_p), lambda di, mi: (di, 0)),
-            pl.BlockSpec((n_p, n_p), lambda di, mi: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((d_p, n_p), acc),
-            jax.ShapeDtypeStruct((n_p, n_p), acc),
-        ],
-        interpret=interpret,
-    )(*inputs)
+    with mosaic_context(interpret):
+        B, G = pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=in_specs.pop("specs"),
+            out_specs=[
+                pl.BlockSpec((bd, n_p), lambda di, mi: (di, 0)),
+                pl.BlockSpec((n_p, n_p), lambda di, mi: (0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((d_p, n_p), acc),
+                jax.ShapeDtypeStruct((n_p, n_p), acc),
+            ],
+            interpret=interpret,
+        )(*inputs)
     return B[:d, :n], G[:n, :n]
 
 
@@ -85,29 +88,29 @@ def countsketch_gram(
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused CountSketch apply + Gram: (B = SA, G = BᵀB), one HBM write of B."""
-    if interpret is None:
-        from ...core.backend import default_interpret
-
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret, A)
     m, n = A.shape
     acc = _acc_dtype(A.dtype)
     bm = min(block_m, max(8, m))
     bd = min(block_d, max(8, d))
 
-    A_p = pad_to(A, (bm, max(128, n)))
-    h_p = pad_to(buckets.astype(jnp.int32)[:, None], (bm, 1))
-    s_p = pad_to(signs.astype(A.dtype)[:, None], (bm, 1))
+    # A is not padded (see countsketch_apply): a partial last m-tile is
+    # masked in the kernel.
+    A_p = pad_to(A, (bm if m < bm else 1, max(128, n)))
+    h_p = pad_to(buckets.astype(jnp.int32)[None, :], (1, bm))
+    s_p = pad_to(signs.astype(jnp.float32)[None, :], (1, bm))
     m_p, n_p = A_p.shape
     specs = dict(
-        m_blocks=m_p // bm,
+        m_blocks=cdiv(m_p, bm),
         specs=[
-            pl.BlockSpec((bm, 1), lambda di, mi: (mi, 0)),
-            pl.BlockSpec((bm, 1), lambda di, mi: (mi, 0)),
+            pl.BlockSpec((1, bm), lambda di, mi: (0, mi)),
+            pl.BlockSpec((1, bm), lambda di, mi: (0, mi)),
             pl.BlockSpec((bm, n_p), lambda di, mi: (mi, 0)),
         ],
     )
+    kernel = partial(countsketch_gram_kernel, m=m_p if m_p % bm else None)
     return _fused_call(
-        countsketch_gram_kernel, (h_p, s_p, A_p), specs, d, n, bd,
+        kernel, (h_p, s_p, A_p), specs, d, n, bd,
         interpret, acc,
     )
 
@@ -122,10 +125,7 @@ def matmul_gram(
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused dense-sketch apply + Gram: (B = SA, G = BᵀB)."""
-    if interpret is None:
-        from ...core.backend import default_interpret
-
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret, S, A)
     d, m = S.shape
     n = A.shape[1]
     acc = _acc_dtype(A.dtype)
@@ -162,10 +162,7 @@ def gaussian_gram(
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused in-kernel-PRNG Gaussian apply + Gram — S never exists in HBM."""
-    if interpret is None:
-        from ...core.backend import default_interpret
-
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret, A)
     m, n = A.shape
     if scale is None:
         scale = 1.0 / float(d) ** 0.5
@@ -249,7 +246,8 @@ def sketch_qr(
     if fusable:
         A_arr = _lowp(A_op.A, True) if mixed else A_op.A
         blocks = backend_lib.kernel_blocks(
-            "tsqr", A_arr.shape[0], A_arr.shape[1], op.d, A_arr.dtype
+            "tsqr", A_arr.shape[0], A_arr.shape[1], op.d, A_arr.dtype,
+            interpret=rb.interpret,
         )
         if isinstance(op, sketch_lib.CountSketch):
             B, G = countsketch_gram(
@@ -275,6 +273,6 @@ def sketch_qr(
 
         B = _sketch_apply(op, A_op, backend=backend, precision=precision)
         B = B.astype(working)
-        G = B.T @ B
+        G = matmul(B.T, B)
     Q, R = cholqr_finish(B, G, rounds=rounds)
     return Q, R, B

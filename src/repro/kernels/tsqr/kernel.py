@@ -27,7 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import bits_to_gaussian, threefry2x32
+from ..common import bits_to_gaussian, mxu_dot, threefry2x32
+from ..countsketch.kernel import masked_rows, signed_onehot
 
 
 def _accumulate_gram(b_ref, g_ref, di, mi, m_blocks):
@@ -40,12 +41,7 @@ def _accumulate_gram(b_ref, g_ref, di, mi, m_blocks):
     @pl.when(mi == m_blocks - 1)
     def _fold():
         b = b_ref[...]
-        g_ref[...] += jax.lax.dot_general(
-            b,
-            b,
-            dimension_numbers=(((0,), (0,)), ((), ())),  # bᵀ·b
-            preferred_element_type=g_ref.dtype,
-        )
+        g_ref[...] += mxu_dot(b, b, g_ref.dtype, contract=((0,), (0,)))  # bᵀ·b
 
 
 def panel_gram_kernel(b_ref, g_ref):
@@ -57,19 +53,16 @@ def panel_gram_kernel(b_ref, g_ref):
         g_ref[...] = jnp.zeros_like(g_ref)
 
     b = b_ref[...]
-    g_ref[...] += jax.lax.dot_general(
-        b,
-        b,
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=g_ref.dtype,
-    )
+    g_ref[...] += mxu_dot(b, b, g_ref.dtype, contract=((0,), (0,)))
 
 
-def countsketch_gram_kernel(buckets_ref, signs_ref, a_ref, b_ref, g_ref):
+def countsketch_gram_kernel(buckets_ref, signs_ref, a_ref, b_ref, g_ref, *, m=None):
     """Fused CountSketch apply + Gram.  Grid: (d_blocks, m_blocks).
 
-    Same one-hot-matmul recast as ``countsketch.kernel`` (padded rows
-    carry sign 0, padded d rows receive no bucket — both Gram-neutral).
+    Same signed one-hot matmul as ``countsketch.kernel`` (padded rows
+    carry sign 0 and a partial last m-tile is masked, padded d rows
+    receive no bucket — all Gram-neutral).  ``m`` is the row count when
+    the last m-tile is partial (else None).
     """
     di = pl.program_id(0)
     mi = pl.program_id(1)
@@ -80,21 +73,9 @@ def countsketch_gram_kernel(buckets_ref, signs_ref, a_ref, b_ref, g_ref):
     def _init():
         b_ref[...] = jnp.zeros_like(b_ref)
 
-    h = buckets_ref[...]  # (bm, 1) int32, global bucket ids
-    s = signs_ref[...]  # (bm, 1)
-    a = a_ref[...]  # (bm, n_pad)
-    bm = a.shape[0]
-
-    local = h - di * bd
-    cols = jax.lax.broadcasted_iota(jnp.int32, (bm, bd), 1)
-    onehot = (cols == local).astype(a.dtype)
-
-    b_ref[...] += jax.lax.dot_general(
-        onehot,
-        s * a,
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=b_ref.dtype,
-    )
+    a = masked_rows(a_ref[...], mi, m)  # (bm, n_pad)
+    p = signed_onehot(buckets_ref[...], signs_ref[...], di, bd, a.dtype)
+    b_ref[...] += mxu_dot(p, a, b_ref.dtype)
     _accumulate_gram(b_ref, g_ref, di, mi, m_blocks)
 
 
@@ -112,9 +93,7 @@ def matmul_gram_kernel(s_ref, a_ref, b_ref, g_ref):
     def _init():
         b_ref[...] = jnp.zeros_like(b_ref)
 
-    b_ref[...] += jnp.dot(
-        s_ref[...], a_ref[...], preferred_element_type=b_ref.dtype
-    )
+    b_ref[...] += mxu_dot(s_ref[...], a_ref[...], b_ref.dtype)
     _accumulate_gram(b_ref, g_ref, di, mi, m_blocks)
 
 
@@ -150,9 +129,7 @@ def make_gaussian_gram_kernel(d: int):
         s_blk = bits_to_gaussian(b0, b1, jnp.float32) * scale_ref[0, 0]
         s_blk = jnp.where(rows < d, s_blk, 0.0)
 
-        b_ref[...] += jnp.dot(
-            s_blk.astype(a.dtype), a, preferred_element_type=b_ref.dtype
-        )
+        b_ref[...] += mxu_dot(s_blk.astype(a.dtype), a, b_ref.dtype)
         _accumulate_gram(b_ref, g_ref, di, mi, m_blocks)
 
     return gaussian_gram_kernel

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.scipy.linalg import solve_triangular
 
-from ..common import cdiv, pad_to
+from ..common import cdiv, matmul, mosaic_context, pad_to, resolve_interpret
 from .kernel import panel_gram_kernel
 
 __all__ = ["panel_gram", "cholqr_finish", "tsqr"]
@@ -52,10 +52,7 @@ def panel_gram(
     One read of B, no n×s intermediate.  ``interpret=None`` resolves via
     ``repro.core.backend.default_interpret``.
     """
-    if interpret is None:
-        from ...core.backend import default_interpret
-
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret, B)
     s, n = B.shape
     acc = jnp.float32 if B.dtype in (jnp.bfloat16, jnp.float16) else B.dtype
 
@@ -64,14 +61,15 @@ def panel_gram(
     B_p = pad_to(B, (br, bn))
     s_p, n_p = B_p.shape
 
-    G = pl.pallas_call(
-        panel_gram_kernel,
-        grid=(s_p // br,),
-        in_specs=[pl.BlockSpec((br, n_p), lambda pi: (pi, 0))],
-        out_specs=pl.BlockSpec((n_p, n_p), lambda pi: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_p, n_p), acc),
-        interpret=interpret,
-    )(B_p)
+    with mosaic_context(interpret):
+        G = pl.pallas_call(
+            panel_gram_kernel,
+            grid=(s_p // br,),
+            in_specs=[pl.BlockSpec((br, n_p), lambda pi: (pi, 0))],
+            out_specs=pl.BlockSpec((n_p, n_p), lambda pi: (0, 0)),
+            out_shape=jax.ShapeDtypeStruct((n_p, n_p), acc),
+            interpret=interpret,
+        )(B_p)
     return G[:n, :n]
 
 
@@ -103,10 +101,10 @@ def cholqr_finish(
     R = jnp.linalg.cholesky(G + shift * jnp.eye(n, dtype=dtype)).T
     Q = solve_triangular(R, B.T, trans=1, lower=False).T
     for _ in range(rounds):
-        G2 = Q.T @ Q
+        G2 = matmul(Q.T, Q)
         R2 = jnp.linalg.cholesky(G2).T
         Q = solve_triangular(R2, Q.T, trans=1, lower=False).T
-        R = R2 @ R
+        R = matmul(R2, R)
     return _positive_diag(Q, R)
 
 
@@ -164,7 +162,7 @@ def tsqr(
     # κ(B·R⁻¹) ≈ 1, so the correction Cholesky is unconditionally safe and
     # restores ‖QᵀQ − I‖ ≈ ε while keeping QR = B to rounding.
     Q = solve_triangular(R, B.T, trans=1, lower=False).T
-    R2 = jnp.linalg.cholesky(Q.T @ Q).T
+    R2 = jnp.linalg.cholesky(matmul(Q.T, Q)).T
     Q = solve_triangular(R2, Q.T, trans=1, lower=False).T
-    Q, R = _positive_diag(Q, R2 @ R)
+    Q, R = _positive_diag(Q, matmul(R2, R))
     return Q, R

@@ -3,11 +3,13 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from ..common import matmul
+
 
 def panel_gram_ref(B: jnp.ndarray) -> jnp.ndarray:
     acc = jnp.float32 if B.dtype in (jnp.bfloat16, jnp.float16) else B.dtype
     Bf = B.astype(acc)
-    return Bf.T @ Bf
+    return matmul(Bf.T, Bf)
 
 
 def tsqr_ref(B: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:

@@ -188,7 +188,7 @@ def build_lowerable(cfg: ModelConfig, shape: ShapeConfig, mesh, n_micro: int):
 def _compile_cell(cfg, shape, mesh, n_micro):
     fn, args = build_lowerable(cfg, shape, mesh, n_micro)
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*args)
         t_lower = time.time() - t0
         t0 = time.time()

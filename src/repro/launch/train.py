@@ -67,7 +67,7 @@ def main():
     step_fn = jit_train_step(cfg, ocfg, mesh, n_micro=args.micro)
     writer = ckpt_lib.AsyncCheckpointer(args.ckpt) if args.ckpt else None
     bspec = NamedSharding(mesh, batch_pspec(mesh))
-    with mesh:
+    with jax.set_mesh(mesh):
         for step in range(start, args.steps):
             batch = jax.tree.map(lambda x: jax.device_put(x, bspec), batch_at(dcfg, step))
             state, metrics = step_fn(state, batch)

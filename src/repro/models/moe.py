@@ -165,13 +165,9 @@ def _moe_gspmd(p, x, cfg: ModelConfig, return_aux: bool):
 
 
 def _current_mesh():
-    try:
-        from jax._src.mesh import thread_resources
-
-        mesh = thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
-    except Exception:
-        return None
+    """The mesh set by ``jax.set_mesh`` (abstract inside ``jit``), or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _moe_shard_map(p, x, cfg: ModelConfig, mesh, return_aux: bool):
@@ -180,7 +176,7 @@ def _moe_shard_map(p, x, cfg: ModelConfig, mesh, return_aux: bool):
     names = mesh.axis_names
     dp_axes = tuple(a for a in ("pod", "data") if a in names)
     tp = "model"
-    tp_size = dict(zip(names, mesh.devices.shape))[tp]
+    tp_size = dict(zip(names, mesh.axis_sizes))[tp]
     expert_mode = E % tp_size == 0 and E >= tp_size
     E_loc = E // tp_size if expert_mode else E
     gated = cfg.act in GATED
@@ -278,13 +274,12 @@ def _moe_shard_map(p, x, cfg: ModelConfig, mesh, return_aux: bool):
             pspecs["shared_gate"] = P(d_fsdp, tp)
     p_in = {k: p[k] for k in pspecs}
 
-    from ..sharding import shard_map_compat
-
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(xspec, pspecs),
         out_specs=(xspec, P()),
+        check_vma=False,
     )
     out, aux = fn(x, p_in)
     if return_aux:
@@ -305,7 +300,7 @@ def moe_apply(p, x, cfg: ModelConfig, return_aux: bool = False):
     if impl in ("auto", "shard_map") and x.ndim == 3:
         mesh = _current_mesh()
         if mesh is not None and "model" in mesh.axis_names:
-            tp_size = dict(zip(mesh.axis_names, mesh.devices.shape))["model"]
+            tp_size = dict(zip(mesh.axis_names, mesh.axis_sizes))["model"]
             if _ffn_shardable(cfg, tp_size):
                 return _moe_shard_map(p, x, cfg, mesh, return_aux)
         if impl == "shard_map":

@@ -38,11 +38,12 @@ import jax
 
 from .lockcheck import make_lock
 
-try:  # suppress spans during jit tracing (abstract, zero-work "execution")
-    from jax.core import trace_state_clean as _trace_state_clean
-except ImportError:  # pragma: no cover - older/newer jax layouts
-    def _trace_state_clean() -> bool:
-        return True
+
+def _trace_state_clean() -> bool:
+    """False while JAX traces (abstract, zero-work "execution"): spans are
+    suppressed there."""
+    return jax.core.trace_ctx.is_top_level()
+
 
 __all__ = [
     "Tracer",

@@ -71,12 +71,7 @@ def sketched_psum_grads(
     """
     n_dev = 1
     for ax in axis_names:
-        # jax.lax.axis_size is newer-JAX only; psum(1, ax) is equivalent
-        # (and constant-folded) on every version.
-        if hasattr(jax.lax, "axis_size"):
-            n_dev *= jax.lax.axis_size(ax)
-        else:
-            n_dev *= jax.lax.psum(1, ax)
+        n_dev *= jax.lax.axis_size(ax)
 
     flat, treedef = jax.tree.flatten(grads)
     flat_ef = treedef.flatten_up_to(ef_state) if ef_state is not None else [None] * len(flat)

@@ -42,6 +42,7 @@ from typing import Any, Hashable
 import jax
 import jax.numpy as jnp
 
+from ..kernels.common import matmul
 from ..obs.lockcheck import make_lock
 
 __all__ = [
@@ -198,14 +199,14 @@ def _solve_bucket_direct(A_stack, b_stack, lam, *, certify: bool):
         A_aug = jnp.concatenate([A_i, jnp.sqrt(lam_i) * eye], axis=0)
         b_aug = jnp.concatenate([b_i, jnp.zeros((n_pad,), b_i.dtype)])
         Q, R = jnp.linalg.qr(A_aug, mode="reduced")
-        x = jax.scipy.linalg.solve_triangular(R, Q.T @ b_aug, lower=False)
-        r = b_aug - A_aug @ x
+        x = jax.scipy.linalg.solve_triangular(R, matmul(Q.T, b_aug), lower=False)
+        r = b_aug - matmul(A_aug, x)
         rnorm = jnp.linalg.norm(r)
         if not certify:
             z = jnp.asarray(jnp.nan, A_stack.dtype)
             return x, rnorm, z, z, z, z
         wg = jax.scipy.linalg.solve_triangular(
-            R, A_aug.T @ r, trans=1, lower=False
+            R, matmul(A_aug.T, r), trans=1, lower=False
         )
         svals = jnp.linalg.svd(R, compute_uv=False)
         tiny = jnp.finfo(R.dtype).tiny

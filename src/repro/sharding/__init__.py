@@ -13,36 +13,32 @@ head-sharded over 'model' inside mixer blocks.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
     "DEFAULT_RULES",
     "OPT_RULES",
     "logical_to_spec",
     "constrain",
+    "make_mesh",
     "named_sharding",
-    "shard_map_compat",
     "tree_pspecs",
 ]
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``jax.shard_map(..., check_vma=False)`` across JAX versions.
+def make_mesh(shape, axis_names, *, devices=None) -> Mesh:
+    """A mesh whose axes are all ``Auto`` (GSPMD-propagated).
 
-    Newer JAX exposes ``jax.shard_map`` with the ``check_vma`` knob; older
-    releases only have ``jax.experimental.shard_map.shard_map`` with the
-    pre-rename ``check_rep``.  Replication checking is disabled either way —
-    every caller here produces replicated outputs by construction (psum-fed).
+    ``jax.make_mesh`` makes ``Explicit`` axes by default; the sharding
+    rules here, the model code and the shard_map solvers place arrays with
+    ``NamedSharding``/``with_sharding_constraint`` and let XLA propagate,
+    which is the ``Auto`` contract.
     """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    return jax.make_mesh(
+        tuple(shape), tuple(axis_names),
+        axis_types=(AxisType.Auto,) * len(axis_names), devices=devices,
     )
+
 
 # logical axis -> physical mesh axis (or tuple of axes), None = replicated
 DEFAULT_RULES: dict[str, object] = {
@@ -91,7 +87,7 @@ def logical_to_spec(axes: tuple, mesh: Mesh, rules=None, shape=None) -> P:
     """
     rules = rules or DEFAULT_RULES
     mesh_axes = set(mesh.axis_names)
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     out = []
     for i, ax in enumerate(axes):
         phys = rules.get(ax, None)
@@ -143,13 +139,9 @@ def constrain(x: jax.Array, axes: tuple, mesh: Mesh | None = None, rules=None):
 
 
 def _current_mesh():
-    try:
-        from jax._src.mesh import thread_resources
-
-        m = thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:
-        return None
+    """The mesh set by ``jax.set_mesh`` (abstract inside ``jit``), or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def tree_pspecs(axes_tree, mesh: Mesh, rules=None, shapes_tree=None):

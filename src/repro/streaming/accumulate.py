@@ -50,8 +50,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..core import backend as backend_lib
 from ..core import sketch as sketch_lib
-from ..sharding import shard_map_compat
-
 __all__ = [
     "SketchAccumulator",
     "make_accumulator",
@@ -254,7 +252,8 @@ def sharded_sketch(A, op, *, mesh, axes=("data",), backend="auto"):
         sub = op.restrict_cols(idx_i)
         return lax.psum(sub.apply(A_i, backend=backend), axes)
 
-    fn = shard_map_compat(
-        local, mesh=mesh, in_specs=(P(axes, None), P(axes)), out_specs=P()
+    fn = jax.shard_map(
+        local, mesh=mesh, in_specs=(P(axes, None), P(axes)), out_specs=P(),
+        check_vma=False,
     )
     return fn(A, idx)

@@ -47,6 +47,7 @@ from ..core.backend import resolve as resolve_backend
 from ..core.iterative import _IMPROVE_FACTOR, _STALL_LIMIT, damping_momentum
 from ..core.precond import SketchedFactor, default_sketch_size
 from ..core.result import SolveResult
+from ..kernels.common import matmul
 from ..obs import trace as obs_trace
 from ..obs.metrics import REGISTRY
 from .accumulate import make_accumulator
@@ -169,7 +170,7 @@ def _stream_matvec(source, x):
     with obs_trace.span("stream.pass2", op="matvec"):
         if callable(mv):
             return obs_trace.maybe_block(mv(x))
-        parts = [jnp.asarray(tile) @ x for _, tile in source.tiles()]
+        parts = [matmul(jnp.asarray(tile), x) for _, tile in source.tiles()]
         return obs_trace.maybe_block(jnp.concatenate(parts, axis=0))
 
 
@@ -183,7 +184,7 @@ def _stream_rmatvec(source, u):
         g = jnp.zeros((n,) + u.shape[1:], u.dtype)
         for offset, tile in source.tiles():
             tile = jnp.asarray(tile)
-            g = g + tile.T @ u[offset : offset + tile.shape[0]]
+            g = g + matmul(tile.T, u[offset : offset + tile.shape[0]])
         return obs_trace.maybe_block(g)
 
 
@@ -206,8 +207,8 @@ def _stream_residual_grad(source, b, x):
         rn2 = jnp.zeros(b.shape[1:], b.dtype)
         for offset, tile in source.tiles():
             tile = jnp.asarray(tile)
-            r_t = b[offset : offset + tile.shape[0]] - tile @ x
-            g = g + tile.T @ r_t
+            r_t = b[offset : offset + tile.shape[0]] - matmul(tile, x)
+            g = g + matmul(tile.T, r_t)
             rn2 = rn2 + jnp.sum(r_t * r_t, axis=0)
         obs_trace.maybe_block(g)
         return rn2, g

@@ -86,13 +86,8 @@ def batch_pspec(mesh: Mesh, rules=None) -> P:
 
 def _constrain_like_opt(grads, cfg):
     """Shard gradient buffers like the optimizer state (ZeRO-2 over pod)."""
-    try:
-        from jax._src.mesh import thread_resources
-
-        mesh = thread_resources.env.physical_mesh
-        if mesh.empty or "pod" not in mesh.axis_names:
-            return grads
-    except Exception:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "pod" not in mesh.axis_names:
         return grads
     axes = tfm.params_axes(cfg)
     specs = tree_pspecs(axes, mesh, OPT_RULES, shapes_tree=grads)
@@ -229,15 +224,14 @@ def make_dp_train_step(
         return jax.tree.map(lambda _: spec, tree)
 
     def step(state, ef, batch):
-        from ..sharding import shard_map_compat
-
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=((specs_like(state, rep), specs_like(ef, rep)),
                       specs_like(batch, row)),
             out_specs=((specs_like(state, rep), specs_like(ef, rep)),
                        {"loss": rep, "grad_norm": rep, "lr": rep}),
+            check_vma=False,
         )
         return fn((state, ef), batch)
 

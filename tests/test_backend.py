@@ -74,6 +74,32 @@ def test_default_interpret():
     assert not default_interpret("tpu")
 
 
+def test_compiled_kernel_refuses_float64():
+    """float64 reaching a compiled kernel raises at dispatch (trace time),
+    naming the fix — no Mosaic traceback, no silent reroute."""
+    from repro.kernels import countsketch_apply
+
+    A = jax.ShapeDtypeStruct((256, 8), jnp.float64)
+    h = jax.ShapeDtypeStruct((256,), jnp.int32)
+    s = jax.ShapeDtypeStruct((256,), jnp.float64)
+    with pytest.raises(TypeError, match="float64.*reference"):
+        jax.eval_shape(
+            lambda A, h, s: countsketch_apply(A, h, s, 32, interpret=False),
+            A, h, s,
+        )
+
+
+def test_interpret_mode_refused_on_tpu(monkeypatch):
+    """With a TPU attached the kernels only run compiled."""
+    from repro.core import backend as backend_lib
+    from repro.kernels.common import resolve_interpret
+
+    monkeypatch.setattr(backend_lib, "default_interpret", lambda p=None: False)
+    assert resolve_interpret(None) is False
+    with pytest.raises(ValueError, match="interpret=True"):
+        resolve_interpret(True)
+
+
 def test_kernel_backed_partition():
     assert kernel_backed("countsketch") and kernel_backed("clarkson_woodruff")
     assert kernel_backed("srht") and kernel_backed("gaussian")
@@ -160,6 +186,20 @@ def test_sketched_lstsq_accepts_backend(prob):
         prob.A, prob.b, jax.random.key(1), materialize_y=False, backend="reference"
     )
     assert relerr(r_ref.x, r_saa.x) < 1e-6
+
+
+def test_sketched_lstsq_compiles_once_per_setting(prob):
+    """Repeated solves with the same settings reuse one compiled program;
+    a new key is data, not a new program."""
+    from repro.core import distributed
+    from repro.sharding import make_mesh
+
+    mesh = make_mesh((1,), ("data",))
+    A, b = shard_rows(mesh, ("data",), prob.A, prob.b)
+    before = distributed._solve._cache_size()
+    for seed in (3, 4, 5):
+        sketched_lstsq(A, b, jax.random.key(seed), mesh=mesh, iter_lim=57)
+    assert distributed._solve._cache_size() == before + 1
 
 
 # --------------------------------------------------------------------------

@@ -162,6 +162,25 @@ def test_padded_vmapped_batch_matches_unbatched(kind):
         assert float(jnp.abs(res.x[i, n:]).max()) <= 1e-8
 
 
+@pytest.mark.parametrize("kind", ["clarkson_woodruff", "uniform_sparse"])
+def test_redraw_marks_only_the_lane_the_shared_draw_failed(kind):
+    """Under PRNGKey(3) the shared sparse draw hashes two of problem 1's
+    padded identity rows into one bucket (its LSQR stops on the condition
+    limit, istop 3).  Only that lane is solved again, under a second draw,
+    and only it reports ``used_fallback``."""
+    from repro.core import saa_sas_batch
+
+    problems = [(A, b, 0.0) for A, b, _ in _mixed_problems(jax.random.PRNGKey(2))]
+    m_pad, n_pad = bucket_shape(40 + 7 * 3, 5)
+    A_stack, b_stack, _ = _stack_padded(problems, m_pad, n_pad)
+    res = saa_sas_batch(
+        A_stack, b_stack, jax.random.PRNGKey(3), sketch=kind, iter_lim=80,
+    )
+    assert [bool(f) for f in res.used_fallback] == [False, True, False, False]
+    # the redraw's own stop code is reported, and it converged
+    assert int(res.istop[1]) not in (3, 6, 7)
+
+
 @pytest.mark.parametrize("kind", SKETCH_KINDS)
 def test_padded_ridge_solve_matches_unbatched(kind):
     """S4 (ridge): padding exactness survives λ > 0 through the sketched

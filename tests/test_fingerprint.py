@@ -49,10 +49,12 @@ def test_inplace_mutation_changes_fingerprint():
 
     A = np.asarray(_A()).copy()
     fp1 = fingerprint(A)
-    A[0, 0] += 1.0
+    orig = A[0, 0]
+    A[0, 0] = orig + 1.0
     fp2 = fingerprint(A)
     assert fp1 != fp2
-    A[0, 0] -= 1.0
+    # restore the exact bytes: (a + 1) - 1 need not round back to a
+    A[0, 0] = orig
     assert fingerprint(A) == fp1
 
 

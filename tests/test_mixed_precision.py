@@ -121,18 +121,18 @@ def test_precisions_registry():
 @pytest.mark.parametrize("kind", sorted(KINDS))
 def test_best_blocks_feasible(kind):
     """Winners exist, carry exactly the kind's knobs, and cost finitely."""
-    blocks = best_blocks(kind, 16384, 128, 512, "float32", device="TPU_v5e")
+    blocks = best_blocks(kind, 16384, 128, 512, "float32", device="TPU v5 lite")
     assert set(blocks) == set(KINDS[kind])
     assert all(isinstance(v, int) and v > 0 for v in blocks.values())
-    cost = predict_cost(kind, 16384, 128, 512, "float32", blocks)
+    cost = predict_cost(kind, 16384, 128, 512, "float32", blocks, "TPU v5 lite")
     assert 0 < cost < float("inf")
 
 
 def test_best_blocks_alias_and_cache_consistency():
     a = best_blocks("uniform_dense", 8192, 64, 256, "float32",
-                    device="TPU_v5e")
+                    device="TPU v5 lite")
     b = best_blocks("sketch_matmul", 8192, 64, 256, "float32",
-                    device="TPU_v5e")
+                    device="TPU v5 lite")
     assert a == b
 
 
@@ -143,19 +143,20 @@ def test_best_blocks_cache_miss_warns_once(caplog):
 
     # an off-sweep shape no committed cache will ever contain
     args = ("countsketch", 12345, 67, 321, "float32")
-    key = autotune._key(*args, device="nonexistent_device")
+    device = "TPU v5 lite"
+    key = autotune._key(*args, device=device)
     autotune._MISS_WARNED.discard(key)
     with caplog.at_level("WARNING", logger="repro.kernels.autotune"):
-        blocks = best_blocks(*args, device="nonexistent_device")
+        blocks = best_blocks(*args, device=device)
     hits = [r for r in caplog.records if key in r.getMessage()]
     assert len(hits) == 1
     assert "fallback" in hits[0].getMessage() or "falling back" in hits[0].getMessage()
     assert str(blocks) in hits[0].getMessage()
-    assert blocks == dict(autotune._model_best(*args[:4], "float32"))
+    assert blocks == dict(autotune._model_best(*args[:4], "float32", device))
 
     caplog.clear()
     with caplog.at_level("WARNING", logger="repro.kernels.autotune"):
-        again = best_blocks(*args, device="nonexistent_device")
+        again = best_blocks(*args, device=device)
     assert again == blocks
     assert not [r for r in caplog.records if key in r.getMessage()]
 
@@ -178,11 +179,27 @@ def test_best_blocks_cache_hit_does_not_warn(caplog):
 def test_kernel_blocks_env_kill_switch(monkeypatch):
     monkeypatch.setenv("REPRO_AUTOTUNE", "0")
     assert backend_lib.kernel_blocks("countsketch", 4096, 64, 256,
-                                     "float32") == {}
+                                     "float32", interpret=True) == {}
     monkeypatch.delenv("REPRO_AUTOTUNE", raising=False)
     blocks = backend_lib.kernel_blocks("countsketch", 4096, 64, 256,
-                                       "float32")
+                                       "float32", interpret=True)
     assert isinstance(blocks, dict)
+
+
+def test_tuner_refuses_unknown_device():
+    """No peak table, no model: the tuner raises, and kernel_blocks lets
+    it through (the compiled path on a CPU host names the CPU)."""
+    from repro.kernels import autotune
+
+    with pytest.raises(ValueError, match="peak table"):
+        best_blocks("countsketch", 12345, 67, 321, "float32",
+                    device="nonexistent_device")
+    with pytest.raises(ValueError, match="peak table"):
+        autotune.predict_cost("countsketch", 4096, 64, 256, "float32", {},
+                              "cpu")
+    with pytest.raises(ValueError, match="peak table"):
+        backend_lib.kernel_blocks("countsketch", 12345, 67, 321, "float32",
+                                  interpret=False)
 
 
 def test_resolve_fused_env(monkeypatch):
