@@ -109,7 +109,10 @@ def _solve_many(
             iter_lim=iter_lim, steptol=steptol,
         )
 
-    res = jax.vmap(solve_col, in_axes=(1, 1))(B, Z0)
+    # Named for the profile: the batched LSQR's device ops carry "lsqr" in
+    # their op_name; the RHS block's sketch above has its kernel's name.
+    with jax.named_scope("lsqr"):
+        res = jax.vmap(solve_col, in_axes=(1, 1))(B, Z0)
     X = factor.precondition(res.x.T)  # (n, k)
     return res._replace(x=X, used_fallback=jnp.zeros(B.shape[1], bool))
 

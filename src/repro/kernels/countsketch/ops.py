@@ -68,6 +68,8 @@ def countsketch_apply(
             out_specs=pl.BlockSpec((bd, bn), lambda ni, di, mi: (di, ni)),
             out_shape=jax.ShapeDtypeStruct((d_p, n_p), acc_dtype),
             interpret=interpret,
+            # The kernel's name in a profile, whatever wraps this call.
+            name="countsketch_apply",
         )(h_p, s_p, A_p)
     # half-precision inputs keep the f32 accumulator dtype (mixed-precision
     # contract: bf16 data, >= f32 sketch output for the QR/refinement stages)

@@ -16,6 +16,10 @@ The instrumentation contract is one idiom at every site::
   one process-global :class:`Tracer`.  A *module-global* active tracer
   (not a contextvar) is deliberate: cluster worker threads and the serve
   pump thread must land their spans in the same trace as the caller.
+- While a tracer is active every span also opens a
+  ``jax.profiler.TraceAnnotation`` of the span's name, so a profile taken
+  meanwhile (``obs.jax_profile``, ``jax.profiler.start_trace``) holds the
+  span as a host event on the device ops' clock, on its thread's line.
 - ``maybe_block`` calls ``jax.block_until_ready`` *only while tracing*,
   so span durations are real device wall time; with tracing off JAX's
   async dispatch is untouched.
@@ -211,7 +215,7 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "_name", "_args", "_t0", "_depth")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_depth", "_annotation")
 
     def __init__(self, tracer: Tracer, name: str, args: dict):
         self._tracer = tracer
@@ -226,6 +230,10 @@ class _Span:
         return True
 
     def __enter__(self):
+        # The name alone: the profiler's host event is then named exactly
+        # as the span is.
+        self._annotation = jax.profiler.TraceAnnotation(self._name)
+        self._annotation.__enter__()
         self._depth = _depth()
         _tls.depth = self._depth + 1
         self._t0 = self._tracer.now_us()
@@ -240,6 +248,7 @@ class _Span:
             "pid": 1, "tid": self._tracer.tid(),
             "depth": self._depth, "args": self._args,
         })
+        self._annotation.__exit__(*exc)
         return False
 
 

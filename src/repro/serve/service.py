@@ -77,22 +77,24 @@ SMALL_PROBLEM_FLOPS = 1 << 26
 
 
 @jax.jit
-def _certify_block(op, factor, B_aug, X, distortion, smin, floor):
+def _certify_batch(op, factor, B_aug, X, distortion, smin, floor):
     """Blocked posterior pieces for a whole RHS batch in one compile:
     residuals, whitened gradients ‖R⁻ᵀAᵀr̂‖ and the certified bounds
-    ‖x̂ − x⋆‖ ≤ ‖Yᵀr̂‖ / (σ_w² σ_min(R)) per column."""
-    dtype = factor.R.dtype
-    tiny = jnp.finfo(dtype).tiny
-    Rres = B_aug - op.matmat(X)
-    WG = factor.rt_solve(op.rmatmat(Rres))
-    wg = jnp.linalg.norm(WG, axis=0)
-    rn = jnp.linalg.norm(Rres, axis=0)
-    xn = jnp.linalg.norm(X, axis=0)
-    eps = jnp.clip(distortion, 0.0, 0.999)
-    sigma_w = jnp.maximum(jnp.minimum(1.0 - eps, floor), tiny)
-    bounds = wg / (sigma_w**2 * jnp.maximum(smin, tiny))
-    rels = bounds / jnp.maximum(xn, tiny)
-    return wg, rn, bounds, rels
+    ‖x̂ − x⋆‖ ≤ ‖Yᵀr̂‖ / (σ_w² σ_min(R)) per column.  Its device ops carry
+    "certify" in their op_name."""
+    with jax.named_scope("certify"):
+        dtype = factor.R.dtype
+        tiny = jnp.finfo(dtype).tiny
+        Rres = B_aug - op.matmat(X)
+        WG = factor.rt_solve(op.rmatmat(Rres))
+        wg = jnp.linalg.norm(WG, axis=0)
+        rn = jnp.linalg.norm(Rres, axis=0)
+        xn = jnp.linalg.norm(X, axis=0)
+        eps = jnp.clip(distortion, 0.0, 0.999)
+        sigma_w = jnp.maximum(jnp.minimum(1.0 - eps, floor), tiny)
+        bounds = wg / (sigma_w**2 * jnp.maximum(smin, tiny))
+        rels = bounds / jnp.maximum(xn, tiny)
+        return wg, rn, bounds, rels
 
 
 @dataclasses.dataclass
@@ -472,7 +474,7 @@ class SolveService:
         if session.reg is not None:
             n = session.A.shape[1]
             B = jnp.concatenate([B, jnp.zeros((n, B.shape[1]), B.dtype)], 0)
-        wg, rn, bounds, rels = _certify_block(
+        wg, rn, bounds, rels = _certify_batch(
             session._solve_op, session.factor, B, X, emb.distortion,
             smin, floor,
         )
@@ -536,26 +538,29 @@ class SolveService:
             certs = self._certify_columns(
                 session, B_full, X, [r.rtol for r in live]
             )
-        X_host = np.asarray(X)
-        host = jax.device_get((res.istop, res.itn, res.rnorm, res.arnorm,
-                               res.used_fallback))
-        for j, r in enumerate(live):
-            cert = certs[j]
-            res_j = self._slice_result(res, host, X_host, j, k_pad)._replace(
-                certificate=cert
-            )
-            if bool(cert.passed):
-                self._resolve(r, res_j, cert, "session", hit, k)
-                continue
-            if not emb_ok:
-                reason = (
-                    "embedding could not be certified even at the maximum "
-                    f"sketch size (distortion {float(cert.distortion):.3f})"
-                )
-            else:
-                reason = None
-            self._retry_slow(r, fp, reason, batch_size=k, cache_hit=hit,
-                             fast_cert=cert)
+        with obs_trace.span("serve.collect", k=k):
+            X_host = np.asarray(X)
+            host = jax.device_get((res.istop, res.itn, res.rnorm, res.arnorm,
+                                   res.used_fallback))
+        with obs_trace.span("serve.resolve", k=k):
+            for j, r in enumerate(live):
+                cert = certs[j]
+                res_j = self._slice_result(
+                    res, host, X_host, j, k_pad
+                )._replace(certificate=cert)
+                if bool(cert.passed):
+                    self._resolve(r, res_j, cert, "session", hit, k)
+                    continue
+                if not emb_ok:
+                    reason = (
+                        "embedding could not be certified even at the "
+                        "maximum sketch size (distortion "
+                        f"{float(cert.distortion):.3f})"
+                    )
+                else:
+                    reason = None
+                self._retry_slow(r, fp, reason, batch_size=k, cache_hit=hit,
+                                 fast_cert=cert)
         return len(reqs)
 
     def _slice_result(self, res, host, X_host, j, k_pad) -> SolveResult:

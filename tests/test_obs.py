@@ -148,6 +148,76 @@ def test_span_is_noop_when_disabled():
     assert obs_trace.current() is None
 
 
+class _Annotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records each one
+    made, with its arguments, and whether it is open."""
+
+    made: list = []
+
+    def __init__(self, *args, **kw):
+        self.args, self.kw, self.state = args, kw, "made"
+        _Annotation.made.append(self)
+
+    def __enter__(self):
+        self.state = "open"
+        return self
+
+    def __exit__(self, *exc):
+        self.state = "closed"
+        return False
+
+
+def test_span_annotates_the_profile_only_while_tracing(monkeypatch):
+    monkeypatch.setattr(_Annotation, "made", [])
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotation)
+    sp = obs_trace.span("off", a=1)
+    assert sp is obs_trace._NOOP  # the shared no-op, nothing built
+    with sp:
+        pass
+    assert _Annotation.made == []
+
+    def traced(x):
+        with obs_trace.span("traced"):
+            return x + 1
+
+    with obs_trace.tracing():
+        jax.jit(traced)(1.0)
+        assert _Annotation.made == []  # suppressed under jit tracing
+        with obs_trace.span("serve.solve", k=3):
+            (ann,) = _Annotation.made
+            # the name alone: the profile's event is named as the span
+            assert ann.args == ("serve.solve",) and ann.kw == {}
+            assert ann.state == "open"
+    assert ann.state == "closed"
+
+
+def test_spans_land_in_the_profile_on_their_thread(tmp_path):
+    from jax.profiler import ProfileData
+
+    def worker():
+        with obs_trace.span("worker.region"):
+            time.sleep(0.002)
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs_trace.tracing(), obs_trace.span("caller.region", k=1):
+            t = threading.Thread(target=worker)
+            t.start()
+            t.join(timeout=60)
+            assert not t.is_alive()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    lines = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for i, line in enumerate(plane.lines):
+            for ev in line.events:
+                lines.setdefault(ev.name, set()).add((plane.name, i))
+    assert len(lines["caller.region"]) == 1
+    assert len(lines["worker.region"]) == 1
+    assert lines["caller.region"] != lines["worker.region"]
+
+
 def test_span_nesting_depth_and_order():
     with obs_trace.tracing() as tr:
         with obs_trace.span("outer", k=1) as outer:
@@ -365,7 +435,8 @@ def test_serve_batch_trace_and_counter_consistency(key):
 
     names = {e["name"] for e in tr.events}
     assert {"serve.submit", "serve.dispatch.session",
-            "serve.solve", "serve.certify"} <= names
+            "serve.solve", "serve.certify", "serve.collect",
+            "serve.resolve"} <= names
     submits = [e for e in tr.events if e["name"] == "serve.submit"]
     assert len(submits) == n_req
     st = svc.stats()
