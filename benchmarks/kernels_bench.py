@@ -53,7 +53,7 @@ def _derived_apply_terms(kind: str, m: int, n: int, d: int) -> str:
     nn = n + 1  # the solvers sketch A and b
     if kind == "countsketch":
         hbm = (m * nn + d * nn) * 4 + m * 8
-        flops = 2 * m * d * nn  # one-hot matmul recast
+        flops = m * nn  # one signed add per entry, no MXU pass
     elif kind == "srht":
         m_pad = 1 << (m - 1).bit_length()
         c = min(1024, m_pad)

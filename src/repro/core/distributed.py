@@ -15,7 +15,7 @@ The sketch is the shared ``repro.core.sketch`` operator of the requested
 kind: sampled ONCE at global size from ``key``, then its per-row parameter
 arrays row-shard with A — each shard rewraps its slice into a local
 operator of the same kind and calls the same backend-dispatched ``apply``
-(reference segment_sum or the Pallas one-hot-matmul kernel, per
+(reference segment_sum or the Pallas scatter-add kernel, per
 ``backend=``).  Note the draw is NOT bit-identical to ``saa_sas(key)``'s:
 that solver derives its sketch key via ``split(key, 3)`` (it also needs
 perturbation/norm keys for the fallback).

@@ -463,7 +463,8 @@ class CountSketch(_OperatorApply):
 
     SA[k] = sum_{i : h(i)=k} s(i) · A[i]  — an exact isometry in expectation
     with no scaling.  Apply cost O(nnz(A)) via segment_sum (reference) or
-    the blocked one-hot-matmul kernel (pallas).
+    the kernel that adds each row of A into its bucket's row of a
+    VMEM-resident output (pallas).
     """
 
     buckets: jax.Array  # (m,) int32 in [0, d)
@@ -481,9 +482,8 @@ class CountSketch(_OperatorApply):
     def apply(self, A, *, backend: str = "auto"):
         rb = backend_lib.resolve(backend)
         if rb.use_pallas:
-            blocks = _tuned_blocks("countsketch", A, self.d, rb)
             return _kernels().countsketch_apply(
-                A, self.buckets, self.signs, self.d, interpret=rb.interpret, **blocks
+                A, self.buckets, self.signs, self.d, interpret=rb.interpret
             )
         A2, vec = _as_2d(A)
         contrib = self.signs[:, None].astype(A2.dtype) * A2

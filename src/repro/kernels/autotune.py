@@ -6,9 +6,11 @@ reports from: predicted time = max(HBM traffic / bandwidth, flops / peak)
 plus a per-grid-step launch overhead.  The traffic term is the one that
 actually differentiates block shapes — grids that revisit an input tile
 across an outer axis (e.g. the dense sketch re-reads S once per n-block,
-every kernel re-reads A once per d-block) pay for each revisit, so larger
+and A is re-read once per d-block) pay for each revisit, so larger
 blocks along the revisited axes trade VMEM footprint for HBM traffic.
 Candidates that overflow the VMEM budget are discarded before costing.
+The CountSketch kernel is not tuned: its blocks follow from the operand's
+shape (``repro.kernels.countsketch.ops``).
 
 Winners are cached in-repo at ``src/repro/kernels/autotune_cache.json``,
 keyed ``"{kind}|m={m}|n={n}|d={d}|{dtype}|{device}"`` with ``device`` the
@@ -52,13 +54,12 @@ _MISS_WARNED: set[str] = set()
 
 # Kernel families the tuner knows, with the block kwargs each accepts.
 KINDS = {
-    "countsketch": ("block_m", "block_d", "block_n"),
     "sketch_matmul": ("block_d", "block_m", "block_n"),
     "gaussian": ("block_d", "block_m", "block_n"),
     "srht": ("block_n",),
     "tsqr": ("block_m", "block_d"),
 }
-_ALIASES = {"uniform_dense": "sketch_matmul", "clarkson_woodruff": "countsketch"}
+_ALIASES = {"uniform_dense": "sketch_matmul"}
 
 # VMEM budget per grid step for the model's working set below: the
 # double-buffered input and output tiles plus the large in-kernel tiles.
@@ -119,15 +120,7 @@ def predict_cost(
     n_blocks = _cdiv(n_p, bn)
 
     flops = 2.0 * m * n * d
-    if kind == "countsketch":
-        # one-hot matmul recast: dense-rate MACs, A re-read per d-block,
-        # bucket/sign columns re-read per (d, n) block.
-        traffic = m * n * b * d_blocks + m * (4 + b) * d_blocks * n_blocks
-        traffic += d * n * b
-        # A tile, bucket and sign rows (sublane-padded to 8), output tile
-        vmem = 2 * (bm * bn * b + 2 * 8 * bm * 4 + bd * bn * acc_b)
-        steps = m_blocks * d_blocks * n_blocks
-    elif kind == "sketch_matmul":
+    if kind == "sketch_matmul":
         traffic = d * m * b * n_blocks + m * n * b * d_blocks + d * n * b
         # S and A tiles, output tile, and the f32 dot result
         vmem = 2 * ((bd * bm + bm * bn) * b + bd * bn * acc_b) + bd * bn * 4
@@ -232,7 +225,7 @@ def best_blocks(
 
     Committed-cache hit first, cost model on miss.  The returned dict uses
     the kernel wrapper's own kwarg names and can be splatted directly:
-    ``countsketch_apply(A, h, s, d, **best_blocks("countsketch", ...))``.
+    ``sketch_matmul(S, A, **best_blocks("sketch_matmul", ...))``.
     """
     kind = _ALIASES.get(kind, kind)
     if kind not in KINDS:

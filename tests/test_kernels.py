@@ -8,6 +8,7 @@ from repro.kernels import (
     fused_gaussian_ref, fused_gaussian_sketch, gaussian_matrix_ref,
     hadamard_transform, sketch_matmul, sketch_matmul_ref, srht_apply,
 )
+from repro.kernels.countsketch.ops import stored_by_columns
 from repro.kernels.srht.ref import hadamard_ref, srht_ref
 
 
@@ -15,17 +16,64 @@ def _tol(dtype):
     return dict(rtol=5e-2, atol=5e-2) if dtype == jnp.bfloat16 else dict(rtol=2e-5, atol=2e-5)
 
 
+def _buckets(m, d, skew):
+    if skew:  # every row in one of three buckets
+        pick = jax.random.randint(jax.random.key(2), (m,), 0, 3)
+        return jnp.array([0, d // 2, d - 1], jnp.int32)[pick]
+    return jax.random.randint(jax.random.key(2), (m,), 0, d, dtype=jnp.int32)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("m,n,d", [(1000, 100, 64), (513, 7, 200),
-                                   (4096, 256, 512), (300, 1, 33), (8, 128, 8)])
-def test_countsketch(m, n, d, dtype):
+@pytest.mark.parametrize("m,n,d,skew", [
+    (1000, 100, 64, False), (513, 7, 200, False), (4096, 256, 512, False),
+    (300, 1, 33, False), (8, 128, 8, False),
+    (4096, 64, 2000, False),  # many more buckets than one MXU tile
+    (300, 40, 2000, False),  # most buckets empty
+    (3000, 130, 700, True),  # heavy skew
+    (2500, 150, 300, False),  # a partial last row tile
+    (3000, 1, 1200, False),  # a vector
+])
+def test_countsketch(m, n, d, skew, dtype):
     A = jax.random.normal(jax.random.key(1), (m, n), dtype)
-    h = jax.random.randint(jax.random.key(2), (m,), 0, d, dtype=jnp.int32)
+    h = _buckets(m, d, skew)
     s = jax.random.rademacher(jax.random.key(3), (m,), dtype)
     got = countsketch_apply(A, h, s, d, interpret=True).astype(jnp.float32)
     want = countsketch_ref(A.astype(jnp.float32), h, s.astype(jnp.float32), d)
     assert got.shape == want.shape
     assert jnp.allclose(got, want, **_tol(dtype))
+
+
+def test_countsketch_vector_returns_a_vector():
+    m, d = 2500, 300
+    b = jax.random.normal(jax.random.key(1), (m,), jnp.float32)
+    h = _buckets(m, d, False)
+    s = jax.random.rademacher(jax.random.key(3), (m,), jnp.float32)
+    got = countsketch_apply(b, h, s, d, interpret=True)
+    assert got.shape == (d,)
+    assert jnp.allclose(got, countsketch_ref(b, h, s, d), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("m,n,by_columns", [
+    (1 << 20, 1000, True), (1 << 20, 1024, False), (4000, 1000, False),
+    (1 << 19, 64, True), (3000, 1, True), (129, 1000, False),
+    (1000, 129, True), (4096, 256, False),
+])
+def test_countsketch_orientation_follows_the_tpu_layout(m, n, by_columns):
+    """The kernel reads A by columns exactly where XLA's TPU layout (the
+    tiling that pads less, rows on a tie) stores it so."""
+    assert stored_by_columns(m, n) is by_columns
+
+
+@pytest.mark.parametrize("d", [64, 2000])
+def test_countsketch_reads_a_in_its_own_order(d):
+    """The kernel adds each row into its bucket where it lies: no sort of
+    the buckets and no gathered copy of A, whatever d is."""
+    A = jax.ShapeDtypeStruct((4096, 256), jnp.float32)
+    h = jax.ShapeDtypeStruct((4096,), jnp.int32)
+    s = jax.ShapeDtypeStruct((4096,), jnp.float32)
+    text = str(jax.make_jaxpr(
+        lambda A, h, s: countsketch_apply(A, h, s, d, interpret=True))(A, h, s))
+    assert "sort" not in text and "gather" not in text
 
 
 @pytest.mark.parametrize("m", [8, 64, 512, 2048, 8192])

@@ -128,6 +128,17 @@ def test_best_blocks_feasible(kind):
     assert 0 < cost < float("inf")
 
 
+def test_countsketch_is_not_tuned():
+    """The CountSketch kernel's blocks follow from its shape: the tuner has
+    no such kind, and the committed cache no such entry."""
+    from repro.kernels import autotune
+
+    for kind in ("countsketch", "clarkson_woodruff"):
+        with pytest.raises(ValueError, match="unknown autotune kind"):
+            best_blocks(kind, 4096, 64, 256, "float32", device="TPU v5 lite")
+    assert not [k for k in autotune._load_cache() if k.startswith("countsketch")]
+
+
 def test_best_blocks_alias_and_cache_consistency():
     a = best_blocks("uniform_dense", 8192, 64, 256, "float32",
                     device="TPU v5 lite")
@@ -142,7 +153,7 @@ def test_best_blocks_cache_miss_warns_once(caplog):
     from repro.kernels import autotune
 
     # an off-sweep shape no committed cache will ever contain
-    args = ("countsketch", 12345, 67, 321, "float32")
+    args = ("sketch_matmul", 12345, 67, 321, "float32")
     device = "TPU v5 lite"
     key = autotune._key(*args, device=device)
     autotune._MISS_WARNED.discard(key)
@@ -178,10 +189,10 @@ def test_best_blocks_cache_hit_does_not_warn(caplog):
 
 def test_kernel_blocks_env_kill_switch(monkeypatch):
     monkeypatch.setenv("REPRO_AUTOTUNE", "0")
-    assert backend_lib.kernel_blocks("countsketch", 4096, 64, 256,
+    assert backend_lib.kernel_blocks("gaussian", 4096, 64, 256,
                                      "float32", interpret=True) == {}
     monkeypatch.delenv("REPRO_AUTOTUNE", raising=False)
-    blocks = backend_lib.kernel_blocks("countsketch", 4096, 64, 256,
+    blocks = backend_lib.kernel_blocks("gaussian", 4096, 64, 256,
                                        "float32", interpret=True)
     assert isinstance(blocks, dict)
 
@@ -192,13 +203,13 @@ def test_tuner_refuses_unknown_device():
     from repro.kernels import autotune
 
     with pytest.raises(ValueError, match="peak table"):
-        best_blocks("countsketch", 12345, 67, 321, "float32",
+        best_blocks("sketch_matmul", 12345, 67, 321, "float32",
                     device="nonexistent_device")
     with pytest.raises(ValueError, match="peak table"):
-        autotune.predict_cost("countsketch", 4096, 64, 256, "float32", {},
+        autotune.predict_cost("sketch_matmul", 4096, 64, 256, "float32", {},
                               "cpu")
     with pytest.raises(ValueError, match="peak table"):
-        backend_lib.kernel_blocks("countsketch", 12345, 67, 321, "float32",
+        backend_lib.kernel_blocks("sketch_matmul", 12345, 67, 321, "float32",
                                   interpret=False)
 
 

@@ -82,6 +82,25 @@ def test_countsketch_compiles_at_fig3_width(one_chip, kernel):
     assert args + temp < HBM_BYTES
 
 
+@pytest.mark.parametrize("shape,dtype", [
+    ((M_FULL, N), jnp.float32),  # A of fig3.fresh, stored by columns
+    ((M_FULL,), jnp.float32),  # its b
+    ((M_FULL, N), jnp.bfloat16),  # precision="mixed"
+    ((M_FULL, 1024), jnp.float32),  # stored by rows
+])
+def test_countsketch_reads_a_where_it_lies(one_chip, shape, dtype):
+    """The apply reads A in the order XLA stores it: no relayout or padded
+    copy of A, only the resident output in VMEM."""
+    A = _spec(one_chip, shape, dtype)
+    h = _spec(one_chip, (M_FULL,), jnp.int32)
+    s = _spec(one_chip, (M_FULL,), dtype)
+    compiled = _compile(
+        lambda A, h, s: countsketch_apply(A, h, s, D, interpret=False), A, h, s)
+    args, temp = _footprint(compiled)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert temp <= 0.01 * args, (args, temp)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_hadamard_compiles_at_fig3_m(one_chip, dtype):
     """SRHT's transform at m = 2^20 fits the 16 MiB scoped VMEM, and
