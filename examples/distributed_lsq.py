@@ -1,6 +1,8 @@
 """Distributed sketch-and-solve: row-sharded A over every device JAX sees.
 
-Each shard applies the shared ``CountSketch`` operator to its local rows
+``lstsq`` sees that A's rows are split over the mesh and runs the
+row-sharded SAA-SAS (``repro.core.distributed.sketched_lstsq``).  Each shard
+applies the shared ``CountSketch`` operator to its local rows
 (into the global bucket space); one s x (n+1) all-reduce assembles the
 sketch; LSQR runs distributed with psum-reduced inner products.
 Communication is independent of m.  ``--backend pallas`` routes the local
@@ -17,7 +19,7 @@ import jax
 jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp
 
-from repro.core import generate_problem, qr_solve, sketched_lstsq
+from repro.core import generate_problem, lstsq, qr_solve
 from repro.core.distributed import shard_rows
 from repro.sharding import make_mesh
 
@@ -34,7 +36,8 @@ def main():
     A, b = shard_rows(mesh, ("data",), prob.A, prob.b)
     print(f"A: {A.shape} sharded as {A.sharding.spec} over {len(jax.devices())} devices")
 
-    res = sketched_lstsq(A, b, jax.random.key(1), mesh=mesh, backend=args.backend)
+    res = lstsq(A, b, jax.random.key(1), backend=args.backend)
+    print(f"lstsq -> {res.method}")
     x_ref = qr_solve(prob.A, prob.b)
     err_vs_truth = float(jnp.linalg.norm(res.x - prob.x_true) / jnp.linalg.norm(prob.x_true))
     err_vs_qr = float(jnp.linalg.norm(res.x - x_ref) / jnp.linalg.norm(x_ref))

@@ -22,10 +22,17 @@ perturbation/norm keys for the fallback).
 
 This is the native multi-pod form of SAA-SAS: compute scales 1/P, the
 collective term is O(s·n) per solve + O(n) per LSQR iteration.
+
+``lstsq`` routes here when A's ``NamedSharding`` splits its rows
+(:func:`row_sharding`).  Inside the compiled solve the local applies and
+their psums are named ``sketch`` and the LSQR loop ``lsqr``
+(``jax.named_scope``, as a profile's ``tf_op`` path shows them); each call
+is a ``repro.obs`` span ``sketched_lstsq`` (chips, m, n, itn) while tracing.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 
 import jax
@@ -37,11 +44,12 @@ from . import backend as backend_lib
 from . import linop
 from . import sketch as sketch_lib
 from ..kernels.common import vdot
+from ..obs import trace as obs_trace
 from .lsqr import lsqr
 from .precond import SketchedFactor, default_sketch_size
 from .result import SolveResult
 
-__all__ = ["sketched_lstsq", "DistributedLSQResult", "shard_rows"]
+__all__ = ["sketched_lstsq", "DistributedLSQResult", "shard_rows", "row_sharding"]
 
 # Superseded by the unified result type.  The alias keeps attribute access
 # working; field order/arity changed (arnorm, used_fallback... added), so
@@ -56,6 +64,29 @@ def shard_rows(mesh, axes, A, b):
     return A, b
 
 
+def mesh_size(mesh, axes) -> int:
+    """Devices along ``axes`` of ``mesh``: the shards of A's rows."""
+    return math.prod(mesh.shape[a] for a in axes)
+
+
+def row_sharding(A):
+    """``(mesh, axes)`` where ``A`` is a 2-D array laid out by a
+    ``NamedSharding`` that splits its rows over more than one device and
+    keeps its columns whole; else None (one device, a replicated or
+    column-split layout, a sparse matrix or operator, a traced value)."""
+    sharding = getattr(A, "sharding", None)
+    if not isinstance(sharding, NamedSharding) or len(A.shape) != 2:
+        return None
+    spec = tuple(sharding.spec) + (None, None)
+    rows, cols = spec[0], spec[1]
+    if cols not in (None, ()) or rows in (None, ()):
+        return None
+    axes = (rows,) if isinstance(rows, str) else tuple(rows)
+    if mesh_size(sharding.mesh, axes) < 2:
+        return None
+    return sharding.mesh, axes
+
+
 # Scatter-family kinds: per-row parameter arrays (field names) and the axis
 # along which those arrays index rows of A — the axis that shards with A.
 _ROW_PARAM_FIELDS = {
@@ -63,6 +94,7 @@ _ROW_PARAM_FIELDS = {
     sketch_lib.UniformSparseSketch: (("buckets", "values"), 0),
     sketch_lib.SparseSignSketch: (("buckets", "signs"), 1),
 }
+ROW_SKETCHES = frozenset(_ROW_PARAM_FIELDS)
 
 
 def sketched_lstsq(
@@ -109,7 +141,7 @@ def sketched_lstsq(
             f"unknown sketch kind {sketch!r}; have "
             f"{sorted(sketch_lib.SKETCH_KINDS)}"
         )
-    if cls not in _ROW_PARAM_FIELDS:
+    if cls not in ROW_SKETCHES:
         raise ValueError(
             f"sketch {sketch!r} has no per-row parameters to shard; the "
             "distributed driver supports the scatter kinds "
@@ -117,15 +149,23 @@ def sketched_lstsq(
         )
     if steptol is None:
         steptol = 32 * float(jnp.finfo(A.dtype).eps)
-    x, istop, itn, rnorm, arnorm = _solve(
-        A, b, key, mesh=mesh, axes=tuple(axes), sketch=sketch,
-        s=sketch_size if sketch_size is not None else default_sketch_size(n, m),
-        atol=float(atol), btol=float(btol), steptol=float(steptol),
-        iter_lim=int(iter_lim), backend=backend_lib.resolve(backend).name,
-    )
+    with obs_trace.span("sketched_lstsq", chips=mesh_size(mesh, axes), m=m,
+                        n=n) as sp:
+        x, istop, itn, rnorm, arnorm = _solve(
+            A, b, key, mesh=mesh, axes=tuple(axes), sketch=sketch,
+            s=sketch_size if sketch_size is not None
+            else default_sketch_size(n, m),
+            atol=float(atol), btol=float(btol), steptol=float(steptol),
+            iter_lim=int(iter_lim), backend=backend_lib.resolve(backend).name,
+        )
+        obs_trace.maybe_block(x)
+        if sp:
+            sp.set(itn=int(itn))
     return SolveResult(
         x=x, istop=istop, itn=itn, rnorm=rnorm, arnorm=arnorm,
         used_fallback=jnp.asarray(False),
+        # a name cannot leave a jit: under one the caller's driver sets it
+        method=None if isinstance(x, jax.core.Tracer) else "saa",
     )
 
 
@@ -159,8 +199,9 @@ def _solve(A, b, key, *, mesh, axes, sketch, s, atol, btol, steptol,
         local_op = dataclasses.replace(
             op, m=A_i.shape[0], **dict(zip(fields, params_i))
         )
-        SA = lax.psum(local_op.apply(A_i, backend=backend), axes)
-        Sb = lax.psum(local_op.apply(b_i, backend=backend), axes)
+        with jax.named_scope("sketch"):
+            SA = lax.psum(local_op.apply(A_i, backend=backend), axes)
+            Sb = lax.psum(local_op.apply(b_i, backend=backend), axes)
 
         # --- replicated small factorization -------------------------------
         factor = SketchedFactor.from_sketch(SA)
@@ -179,10 +220,11 @@ def _solve(A, b, key, *, mesh, axes, sketch, s, atol, btol, steptol,
         def udot(u, w):
             return lax.psum(vdot(u, w), axes)
 
-        res = lsqr(
-            mv, rmv, b_i, x0=z0, n=n, atol=atol, btol=btol,
-            steptol=steptol, iter_lim=iter_lim, udot=udot,
-        )
+        with jax.named_scope("lsqr"):
+            res = lsqr(
+                mv, rmv, b_i, x0=z0, n=n, atol=atol, btol=btol,
+                steptol=steptol, iter_lim=iter_lim, udot=udot,
+            )
         x = factor.precondition(res.x)
         return x, res.istop, res.itn, res.rnorm, res.arnorm
 

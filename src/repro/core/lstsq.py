@@ -50,6 +50,18 @@ Auto-selection (``method="auto"``):
   appended √λ·I rows used to inflate m and mis-classify near-boundary
   ridge problems as sketchable).
 
+A dense array whose ``NamedSharding`` splits its rows over more than one
+device (and keeps its columns whole) takes the mesh path:
+:func:`repro.core.distributed.sketched_lstsq`, the row-sharded SAA-SAS, on
+the mesh and axes of ``A.sharding`` (``b`` is placed beside A's rows).
+Each chip sketches its own rows and one psum assembles the sketch; no
+collective moves A.  Of the methods only ``saa`` has that form, so
+``method="auto"`` with ``accuracy`` "fast" or "balanced" selects it, and
+the options it has no form for (``accuracy`` "high"/"certified",
+``reg=``, ``precision="mixed"``, ``cluster=``, ``fused=``,
+``history=True``, another forced method, a sketch kind without per-row
+parameters) raise instead of gathering A onto one device.
+
 ``accuracy="certified"`` is the fourth, adaptive tier: solve, then
 *certify* the answer with the posterior estimators of
 ``repro.core.certify`` (embedding-distortion probe, cond(R), a forward
@@ -74,9 +86,11 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import backend as backend_lib
 from . import certify as certify_lib
+from . import distributed
 from . import linop
 from ..kernels.common import matmul
 from ..obs import trace as obs_trace
@@ -94,6 +108,7 @@ from .precond import SketchedFactor, default_sketch_size
 from .result import SolveResult
 from .saa import _solve_with_factor, saa_sas
 from .sap import sap_sas
+from .sketch import SKETCH_KINDS
 
 __all__ = [
     "lstsq",
@@ -455,6 +470,72 @@ def lstsq(
     return scope.attach(res)
 
 
+def _given(**tol):
+    """The tolerance knobs the caller set (``None`` leaves a solver's own
+    default)."""
+    return {k: v for k, v in tol.items() if v is not None}
+
+
+# The accuracies the mesh path's one method, SAA-SAS, serves.
+_MESH_ACCURACIES = ("fast", "balanced")
+
+
+def _mesh_lstsq(A, b, key, mesh_rows, *, method, accuracy, sketch,
+                sketch_size, reg, atol, btol, steptol, iter_lim, backend,
+                precision, fused, history, cluster) -> SolveResult:
+    """``A`` row-sharded over ``mesh_rows = (mesh, axes)``: the distributed
+    SAA-SAS, or a ``ValueError`` for what it has no form for."""
+    mesh, axes = mesh_rows
+    method = _ALIASES.get(method, method)
+    refused = []
+    if method not in ("auto", "saa"):
+        refused.append(f"method={method!r}")
+    if accuracy not in _MESH_ACCURACIES:
+        refused.append(f"accuracy={accuracy!r}")
+    if reg is not None:
+        refused.append("reg=")
+    if precision != "full":
+        refused.append(f"precision={precision!r}")
+    if cluster is not None:
+        refused.append("cluster=")
+    if fused:
+        refused.append("fused=True")
+    if history:
+        refused.append("history=True")
+    if SKETCH_KINDS.get(sketch) not in distributed.ROW_SKETCHES:
+        refused.append(f"sketch={sketch!r}")
+    if key is None:
+        refused.append("key=None")
+    if refused:
+        kinds = sorted(k for k, c in SKETCH_KINDS.items()
+                       if c in distributed.ROW_SKETCHES)
+        raise ValueError(
+            f"A is row-sharded over {axes} of its mesh; the mesh path "
+            f"(row-sharded SAA-SAS) cannot take {', '.join(refused)}. It "
+            f"supports method 'auto' or 'saa', accuracy in "
+            f"{_MESH_ACCURACIES}, full precision, a PRNG key, the sketch "
+            f"kinds {kinds}, sketch_size, atol/btol/steptol/iter_lim and "
+            "backend. Gather A onto one device yourself to use the rest."
+        )
+    m, n = A.shape
+    with obs_trace.span("lstsq.select", m=m, n=n, accuracy=accuracy,
+                        chips=distributed.mesh_size(mesh, axes),
+                        method="saa"):
+        pass
+    b_rows = NamedSharding(mesh, P(axes))
+    if getattr(b, "sharding", None) != b_rows:
+        b = jax.device_put(b, b_rows)
+    tol = _given(atol=atol, btol=btol, steptol=steptol, iter_lim=iter_lim)
+    with obs_trace.span("lstsq.solve", method="saa") as sp:
+        res = distributed.sketched_lstsq(
+            A, b, key, mesh=mesh, axes=axes, sketch=sketch,
+            sketch_size=sketch_size, backend=backend, **tol,
+        )
+        if sp:
+            sp.set(itn=int(res.itn))
+    return res
+
+
 def _lstsq_impl(
     A,
     b,
@@ -483,6 +564,15 @@ def _lstsq_impl(
         raise ValueError(
             f"unknown precision {precision!r}; have {backend_lib.PRECISIONS}"
         )
+    mesh_rows = distributed.row_sharding(A)
+    if mesh_rows is not None:
+        return _mesh_lstsq(
+            A, b, key, mesh_rows, method=method, accuracy=accuracy,
+            sketch=sketch, sketch_size=sketch_size, reg=reg, atol=atol,
+            btol=btol, steptol=steptol, iter_lim=iter_lim, backend=backend,
+            precision=precision, fused=fused, history=history,
+            cluster=cluster,
+        )
     if cluster is not None and not callable(getattr(A, "tiles", None)):
         # cluster solving is a streaming mode: coerce in-memory inputs
         from ..streaming.sources import as_source as _as_source
@@ -496,12 +586,8 @@ def _lstsq_impl(
         # escalation ladder, certification just rides along.
         from ..streaming.solve import stream_lstsq as _stream_lstsq
 
-        tol = {
-            k: v
-            for k, v in dict(atol=atol, btol=btol, steptol=steptol,
-                             iter_lim=iter_lim).items()
-            if v is not None
-        }
+        tol = _given(atol=atol, btol=btol, steptol=steptol,
+                     iter_lim=iter_lim)
         return _stream_lstsq(
             A, b, key, method=method, sketch=sketch,
             sketch_size=sketch_size, reg=reg, backend=backend,
@@ -524,12 +610,7 @@ def _lstsq_impl(
     method = _ALIASES.get(method, method)
     forced = method != "auto"
 
-    tol = {
-        k: v
-        for k, v in dict(atol=atol, btol=btol, steptol=steptol,
-                         iter_lim=iter_lim).items()
-        if v is not None
-    }
+    tol = _given(atol=atol, btol=btol, steptol=steptol, iter_lim=iter_lim)
 
     if accuracy == "certified":
         if forced:

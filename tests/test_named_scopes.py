@@ -1,7 +1,9 @@
 """The solver names its layers inside its jitted programs.
 
-``refine`` (the heavy-ball loop), ``lsqr`` (the service's batched LSQR) and
-``certify`` (the service's blocked certificate) are ``jax.named_scope``
+``refine`` (the heavy-ball loop), ``lsqr`` (the service's batched LSQR and
+the row-sharded solve's distributed LSQR), ``sketch`` (the row-sharded
+solve's local sketch applies and their psums) and ``certify`` (the
+service's blocked certificate) are ``jax.named_scope``
 components of the ops' locations, which XLA keeps as their ``op_name``
 metadata and a profile shows beside each device op.  A scope is matched as
 a whole path component: ``lsqr.<locals>.body`` is not ``lsqr``.
@@ -13,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import distributed
 from repro.core.iterative import iterative_sketching
 from repro.core.session import SketchedSolver, _solve_many
 from repro.serve.service import _certify_batch
@@ -70,3 +73,19 @@ def test_certify_scope_in_certify_batch(session):
     certify = _under(paths, "certify")
     assert any(any(c.startswith("jit(_solve_triangular") for c in p)
                for p in certify)
+
+
+def test_lsqr_and_sketch_scopes_in_the_row_sharded_solve(problem):
+    from repro.sharding import make_mesh
+
+    A, b, key = problem
+    mesh = make_mesh((1,), ("data",))
+    paths = _paths(distributed._solve.lower(
+        A, b, key, mesh=mesh, axes=("data",), sketch="clarkson_woodruff",
+        s=64, atol=0.0, btol=0.0, steptol=1e-6, iter_lim=10,
+        backend="reference"))
+    lsqr = _under(paths, "lsqr")
+    assert any("while" in p for p in lsqr)  # the loop, its psums inside
+    sketch = _under(paths, "sketch")
+    assert sketch and not any("while" in p for p in sketch)
+    assert not [p for p in lsqr if "sketch" in p]

@@ -353,6 +353,44 @@ def test_lstsq_traced_attaches_nested_timeline(key):
     assert not obs_trace.enabled()  # per-call scope released the tracer
 
 
+def _row_sharded(A, b):
+    """(A, b, mesh) row-sharded over a one-device mesh: the distributed
+    driver's own code path, on the one device a test process has."""
+    from repro.core.distributed import shard_rows
+    from repro.sharding import make_mesh
+
+    mesh = make_mesh((1,), ("data",))
+    return (*shard_rows(mesh, ("data",), A, b), mesh)
+
+
+def test_sketched_lstsq_traced_records_its_span(key):
+    from repro.core.distributed import sketched_lstsq
+
+    A, b, mesh = _row_sharded(*_problem(m=512, n=8))
+    with obs_trace.tracing() as tr:
+        res = sketched_lstsq(A, b, key, mesh=mesh)
+    spans = [s for s in tr.timeline().spans() if s["name"] == "sketched_lstsq"]
+    assert len(spans) == 1
+    assert spans[0]["args"] == {"chips": 1, "m": 512, "n": 8,
+                                "itn": int(res.itn)}
+    assert res.method == "saa"
+
+
+def test_sketched_lstsq_untraced_costs_nothing(key, monkeypatch):
+    """Tracing off: no span object is made and nothing blocks."""
+    from repro.core.distributed import sketched_lstsq
+
+    A, b, mesh = _row_sharded(*_problem(m=512, n=8))
+    made, blocked = [], []
+    monkeypatch.setattr(obs_trace, "_Span",
+                        lambda *a, **k: made.append(a))
+    monkeypatch.setattr(obs_trace.jax, "block_until_ready",
+                        lambda x: blocked.append(x) or x)
+    res = sketched_lstsq(A, b, key, mesh=mesh)
+    assert not made and not blocked
+    assert res.timeline is None
+
+
 def test_certified_trace_shows_rungs_and_probes(key):
     A, b = _problem(m=512, n=8)
     res = lstsq(A, b, key, accuracy="certified", trace=True)

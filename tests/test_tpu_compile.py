@@ -154,3 +154,45 @@ def test_sharded_solve_compiles_on_four_chips(v5e):
         )
     assert "tpu_custom_call" in compiled.as_text()
     assert smoke.check_sharded_program(compiled, M_SHARDED, N, 4, HBM_BYTES) == []
+
+
+def test_lstsq_on_a_sharded_a_compiles_on_four_chips(v5e):
+    """``lstsq`` given A (2^22 x 1000 f32) row-sharded over the 2x2 host
+    takes the mesh path, and the program it runs there passes the checks
+    of the twin test above.  lstsq routes on the arrays' shardings, which
+    described shapes carry too; the call it makes to ``sketched_lstsq`` is
+    compiled here in place of being run."""
+    from repro.core import distributed, lstsq
+
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", Path(__file__).resolve().parent.parent / "chip_smoke.py"
+    )
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    mesh = make_mesh((4,), ("data",), devices=v5e.devices)
+    A = _spec(NamedSharding(mesh, P("data", None)), (M_SHARDED, N), jnp.float32)
+    b = _spec(NamedSharding(mesh, P("data")), (M_SHARDED,), jnp.float32)
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                               sharding=NamedSharding(mesh, P()))
+    calls, solve = [], distributed.sketched_lstsq
+
+    def compile_instead(A, b, key, **kw):
+        calls.append(kw)
+        calls.append(_compile(partial(solve, **kw), A, b, key))
+        zero = jnp.zeros((), jnp.int32)
+        return distributed.SolveResult(
+            x=jnp.zeros(N), istop=zero, itn=zero, rnorm=zero, arnorm=zero,
+            used_fallback=jnp.asarray(False), method="saa")
+
+    blocks = backend_lib.kernel_blocks
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(backend_lib, "default_interpret", lambda platform=None: False)
+        mp.setattr(backend_lib, "kernel_blocks",
+                   lambda *a, **k: blocks(*a, **{**k, "interpret": True}))
+        mp.setattr(distributed, "sketched_lstsq", compile_instead)
+        res = lstsq(A, b, key, backend="pallas")
+    assert res.method == "saa"
+    kw, compiled = calls
+    assert kw["mesh"] is mesh and kw["axes"] == ("data",)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert smoke.check_sharded_program(compiled, M_SHARDED, N, 4, HBM_BYTES) == []
