@@ -21,6 +21,13 @@ benchmark run without touching call sites.
 Sketch kinds without a matching kernel (``sparse_sign``, ``uniform_sparse``)
 fall back to the reference path under ``"pallas"``; ``kernel_backed`` tells
 you which kinds actually dispatch.
+
+The same knob picks the heavy-ball refinement's pass over A
+(``repro.core.iterative.heavy_ball_refine``): under ``"pallas"`` a dense
+float32 A with a vector right-hand side is read once per iteration by the
+``residual_pass`` kernel (``repro.core.linop.one_pass``); under
+``"reference"``, and for every other input, r = b − A x and Aᵀr are two
+XLA products.
 """
 from __future__ import annotations
 

@@ -116,9 +116,12 @@ class _IterState(NamedTuple):
     rhist: jax.Array  # (iter_lim,) or (0,)
 
 
+@resolve_backend_arg
 @partial(
     jax.jit,
-    static_argnames=("atol", "btol", "steptol", "iter_lim", "history"),
+    static_argnames=(
+        "atol", "btol", "steptol", "iter_lim", "backend", "history",
+    ),
 )
 def heavy_ball_refine(
     A,
@@ -132,6 +135,7 @@ def heavy_ball_refine(
     btol: float = 0.0,
     steptol: float,
     iter_lim: int = 100,
+    backend: str = "auto",
     history: bool = False,
 ) -> SolveResult:
     """The damped/momentum iteration of :func:`iterative_sketching` against
@@ -141,6 +145,10 @@ def heavy_ball_refine(
     driver (``repro.core.lstsq``) re-run the refinement after escalating an
     existing factor — the sketch is extended, never redrawn, and only this
     loop repeats.  Same stopping semantics as ``iterative_sketching``.
+
+    Each step's r = b − A x and Aᵀr come from ``A.residual_gradient``:
+    under ``backend="pallas"`` a dense float32 A with a vector b is read
+    once per step (``linop.one_pass``), otherwise they are two products.
     """
     # The loop and the residual of the returned iterate, named for the
     # profile: their device ops carry "refine" in their op_name.
@@ -167,9 +175,9 @@ def heavy_ball_refine(
 
         def body(st: _IterState):
             itn = st.itn + 1
-            r = b - A.matvec(st.x)
-            rnorm = jnp.linalg.norm(r)
-            g = A.rmatvec(r)  # true gradient (up to sign)
+            # the true gradient (up to sign) and the residual's norm
+            g, rsq = A.residual_gradient(st.x, b, backend=backend)
+            rnorm = jnp.sqrt(rsq)
             arnorm = jnp.linalg.norm(g)
             d = factor.normal_solve(g)  # sketched-Hessian solve
             dx = alpha * d + beta * (st.x - st.x_prev)
@@ -207,13 +215,12 @@ def heavy_ball_refine(
         final = lax.while_loop(cond, body, init)
         # Report the residual of the RETURNED iterate (the loop's
         # rnorm/arnorm lag one update behind final.x).
-        r = b - A.matvec(final.x)
-        g = A.rmatvec(r)
+        g, rsq = A.residual_gradient(final.x, b, backend=backend)
         return SolveResult(
             x=final.x,
             istop=jnp.where(bnorm == 0, 0, final.istop),
             itn=final.itn,
-            rnorm=jnp.linalg.norm(r),
+            rnorm=jnp.sqrt(rsq),
             arnorm=jnp.linalg.norm(g),
             used_fallback=jnp.asarray(False),
             history=final.rhist if history else None,
@@ -281,7 +288,7 @@ def iterative_sketching(
     return heavy_ball_refine(
         A, b, factor, x0, alpha, beta,
         atol=atol, btol=btol, steptol=steptol, iter_lim=iter_lim,
-        history=history,
+        backend=backend, history=history,
     )
 
 

@@ -43,6 +43,8 @@ from jax import lax
 from jax.experimental.sparse import BCOO
 
 from ..kernels.common import matmul
+from ..kernels.residual_pass.ops import fits, residual_pass
+from . import backend as backend_lib
 
 __all__ = [
     "LinearOperator",
@@ -52,6 +54,7 @@ __all__ = [
     "CustomOperator",
     "as_operator",
     "ensure_dense",
+    "one_pass",
     "estimate_2norm",
 ]
 
@@ -96,6 +99,15 @@ class LinearOperator:
 
     def rmatmat(self, U: jax.Array) -> jax.Array:
         return jax.vmap(self.rmatvec, in_axes=1, out_axes=1)(U)
+
+    def residual_gradient(self, x: jax.Array, b: jax.Array, *,
+                          backend: str = "reference"):
+        """(g, rsq) = (Aᵀ(b − A x), ‖b − A x‖²): the two products a
+        least-squares iteration takes each step.  ``backend`` is a
+        resolved name of ``repro.core.backend``; operators with a one-pass
+        form (:class:`DenseOperator`) use it under ``"pallas"``."""
+        r = b - self.matvec(x)
+        return self.rmatvec(r), jnp.sum(r * r)
 
     def __matmul__(self, other):
         other = jnp.asarray(other)
@@ -143,6 +155,17 @@ class DenseOperator(LinearOperator):
 
     def rmatmat(self, U):
         return matmul(self.A.T, U)
+
+    def residual_gradient(self, x, b, *, backend="reference"):
+        """Under ``backend="pallas"``, a float32 A with vectors x and b
+        (``one_pass``) is read once (``kernels.residual_pass``); anything
+        else takes the two products."""
+        if (one_pass(self, b, backend=backend) and jnp.ndim(x) == 1
+                and jnp.result_type(x) == jnp.float32):
+            return residual_pass(
+                self.A, x, b,
+                interpret=backend_lib.resolve(backend).interpret)
+        return super().residual_gradient(x, b)
 
     @property
     def materializable(self):
@@ -327,6 +350,21 @@ def as_operator(A) -> LinearOperator:
     if A.ndim != 2:
         raise ValueError(f"need a 2-D matrix, got shape {A.shape}")
     return DenseOperator(A)
+
+
+def one_pass(A, b, *, backend: str) -> bool:
+    """Whether a least-squares iteration on (A, b) reads A once a step
+    (``DenseOperator.residual_gradient``): ``backend`` resolves to
+    ``"pallas"``, A is a dense float32 matrix whose tile fits the kernel,
+    and b is a float32 vector.  A static test on what the inputs show."""
+    A = as_operator(A)
+    f32 = jnp.dtype(jnp.float32)
+    return (
+        isinstance(A, DenseOperator)
+        and backend_lib.resolve(backend).use_pallas
+        and jnp.dtype(A.dtype) == f32 and fits(*A.shape)
+        and jnp.ndim(b) == 1 and jnp.result_type(b) == f32
+    )
 
 
 def ensure_dense(A, *, who: str = "this solver") -> jax.Array:

@@ -326,8 +326,11 @@ def _certified_lstsq(
                     res = heavy_ball_refine(
                         A_op, b_solve, factor, x0, alpha, beta,
                         atol=atol, btol=btol, steptol=steptol,
-                        iter_lim=iter_lim, history=history,
+                        iter_lim=iter_lim, backend=backend, history=history,
                     )
+                    if rung_span:
+                        rung_span.set(one_pass=linop.one_pass(
+                            A_op, b_solve, backend=backend))
                 else:  # fossils
                     res = fossils_refine(
                         A_op, b_solve, factor, op, x0, alpha, beta,
@@ -692,6 +695,8 @@ def _lstsq_impl(
         obs_trace.maybe_block(res.x)
         if sp:
             sp.set(itn=int(res.itn))
+            if method == "iterative":
+                sp.set(one_pass=linop.one_pass(A_op, b_solve, backend=backend))
 
     if reg is not None:
         # Report diagnostics of the ORIGINAL problem, not the augmented one.

@@ -17,6 +17,7 @@ elsewhere, so CPU containers still execute the exact kernel semantics) —
 lives in one policy module, ``repro.core.backend``.
 """
 from .countsketch import countsketch_apply, countsketch_ref
+from .residual_pass import residual_pass, residual_pass_ref
 from .sketch_matmul import (
     fused_gaussian_ref,
     fused_gaussian_sketch,
@@ -44,6 +45,8 @@ __all__ = [
     "tsqr_ref",
     "countsketch_apply",
     "countsketch_ref",
+    "residual_pass",
+    "residual_pass_ref",
     "fused_gaussian_ref",
     "fused_gaussian_sketch",
     "gaussian_cols_ref",

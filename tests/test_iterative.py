@@ -124,3 +124,139 @@ def test_custom_coefficients_still_converge(prob):
 def test_iterative_other_sketches(prob):
     res = iterative_sketching(prob.A, prob.b, jax.random.key(7), sketch="gaussian")
     assert relerr(res.x, prob.x_true) < 1e-5
+
+
+# ---------------------------------------------------------------------------
+# the one-pass refinement: backend="pallas" on a dense float32 A reads A once
+# per heavy-ball step through the residual_pass kernel (interpret mode here)
+
+
+def _f32_problem(m, n):
+    """test_backend_parity's draw in float32 at a condition float32
+    resolves, with a residual, so r = b − A x is not rounding noise."""
+    return generate_problem(jax.random.key(3), m, n, cond=1e2, beta=1e-3,
+                            dtype=jnp.float32)
+
+
+def _residual_pass_calls(fn, *args, **kw):
+    """Paths (enclosing primitives) of the residual_pass kernels fn traces."""
+    from jax.extend import core as jex
+
+    found = []
+
+    def walk(jaxpr, path):
+        for eqn in jaxpr.eqns:
+            if (eqn.primitive.name == "pallas_call"
+                    and eqn.params["name"] == "residual_pass"):
+                found.append(path)
+            for v in eqn.params.values():
+                for sub in v if isinstance(v, (tuple, list)) else (v,):
+                    if isinstance(sub, jex.ClosedJaxpr):
+                        sub = sub.jaxpr
+                    if isinstance(sub, jex.Jaxpr):
+                        walk(sub, path + (eqn.primitive.name,))
+
+    walk(jax.make_jaxpr(lambda *a: fn(*a, **kw))(*args).jaxpr, ())
+    return found
+
+
+@pytest.mark.parametrize("m,n", [(1024, 24), (4000, 64)])
+@pytest.mark.parametrize("entry", ["iterative_sketching", "heavy_ball_refine"])
+def test_one_pass_refinement_matches_two_products(entry, m, n):
+    """The kernel's loop stops as the two-product loop does and returns the
+    same x, whether the whole solve (its sketch on the Pallas kernel too)
+    or only the refinement on one prebuilt factor changes backend."""
+    from repro.core import heavy_ball_refine
+    from repro.core.precond import SketchedFactor
+
+    prob = _f32_problem(m, n)
+    key = jax.random.key(5)
+    if entry == "iterative_sketching":
+        def solve(backend):
+            return iterative_sketching(prob.A, prob.b, key, backend=backend)
+    else:
+        s = 4 * n
+        factor, op = SketchedFactor.build(prob.A, key, sketch_size=s,
+                                          backend="reference")
+        x0 = factor.sketch_and_solve(op.apply(prob.b, backend="reference"))
+        alpha, beta = damping_momentum(s, n)
+
+        def solve(backend):
+            return heavy_ball_refine(
+                prob.A, prob.b, factor, x0, alpha, beta, backend=backend,
+                steptol=32 * float(jnp.finfo(jnp.float32).eps))
+    r_ref, r_pal = solve("reference"), solve("pallas")
+    assert int(r_pal.istop) == int(r_ref.istop) == 8
+    assert abs(int(r_pal.itn) - int(r_ref.itn)) <= 2
+    assert relerr(r_ref.x, r_pal.x) < 1e-6
+    assert relerr(r_pal.x, prob.x_true) < 1e-5
+    assert float(r_pal.rnorm) == pytest.approx(float(r_ref.rnorm), rel=1e-5)
+    assert float(r_pal.arnorm) <= 10 * float(r_ref.arnorm) + 1e-6
+
+
+@pytest.mark.parametrize("case", ["dense", "2-D b", "BCOO", "reg", "f64",
+                                  "reference"])
+def test_one_pass_only_where_the_inputs_allow_it(case):
+    """The kernel runs inside the loop and once for the returned iterate
+    for a dense float32 A and a vector b under "pallas"; a block of
+    right-hand sides, a sparse A, the ridge operator, float64 data and the
+    reference backend take the two products."""
+    from jax.experimental.sparse import BCOO
+
+    from repro.core import heavy_ball_refine, linop
+    from repro.core.precond import SketchedFactor
+
+    prob = _f32_problem(1024, 24)
+    A, b, key = prob.A, prob.b, jax.random.key(5)
+    backend = "reference" if case == "reference" else "pallas"
+    if case == "2-D b":
+        factor, op = SketchedFactor.build(A, key, sketch_size=96,
+                                          backend=backend)
+        B = jnp.stack([b, 2 * b], axis=1)
+        X0 = factor.sketch_and_solve(op.apply(B, backend=backend))
+        calls = _residual_pass_calls(
+            heavy_ball_refine, A, B, factor, X0, 0.5, 0.2, steptol=1e-6,
+            backend=backend)
+        assert not linop.one_pass(A, B, backend=backend)
+        assert calls == []
+        return
+    if case == "BCOO":
+        A = BCOO.fromdense(A)
+    elif case == "reg":
+        A = linop.TikhonovAugmented.wrap(A, 0.1)
+        b = A.augment_rhs(b)
+    elif case == "f64":
+        A, b = A.astype(jnp.float64), b.astype(jnp.float64)
+    calls = _residual_pass_calls(iterative_sketching, A, b, key,
+                                 backend=backend)
+    assert linop.one_pass(A, b, backend=backend) == (case == "dense")
+    if case == "dense":
+        assert sorted(calls, key=len) == [("jit", "jit", "jit"),
+                                          ("jit", "jit", "while", "jit")]
+    else:
+        assert calls == []
+
+
+@pytest.mark.parametrize("accuracy", ["balanced", "certified"])
+@pytest.mark.parametrize("dtype,expected", [(jnp.float32, True),
+                                            (jnp.float64, False)])
+def test_lstsq_records_whether_the_refinement_reads_a_once(
+        dtype, expected, accuracy):
+    """lstsq's heavy-ball callers record ``one_pass`` on their span: the
+    ``lstsq.solve`` of method="iterative", and the certified ladder's
+    "iterative" rung (reached here by a target no rung meets)."""
+    from repro.core import lstsq
+
+    prob = _f32_problem(1024, 24)
+    A, b = prob.A.astype(dtype), prob.b.astype(dtype)
+    if accuracy == "certified":
+        res = lstsq(A, b, jax.random.key(5), accuracy="certified",
+                    certified_rtol=1e-30, backend="pallas", trace=True)
+        name, method = "certified.rung", "iterative"
+    else:
+        res = lstsq(A, b, jax.random.key(5), method="iterative",
+                    backend="pallas", trace=True)
+        name, method = "lstsq.solve", "iterative"
+    spans = [s["args"] for s in res.timeline.spans()
+             if s["name"] == name and s["args"]["method"] == method]
+    assert len(spans) == 1 and spans[0]["one_pass"] is expected

@@ -6,9 +6,11 @@ import pytest
 from repro.kernels import (
     countsketch_apply, countsketch_ref,
     fused_gaussian_ref, fused_gaussian_sketch, gaussian_matrix_ref,
-    hadamard_transform, sketch_matmul, sketch_matmul_ref, srht_apply,
+    hadamard_transform, residual_pass, residual_pass_ref, sketch_matmul,
+    sketch_matmul_ref, srht_apply,
 )
 from repro.kernels.countsketch.ops import stored_by_columns
+from repro.kernels.residual_pass.ops import _tile_rows
 from repro.kernels.srht.ref import hadamard_ref, srht_ref
 
 
@@ -74,6 +76,46 @@ def test_countsketch_reads_a_in_its_own_order(d):
     text = str(jax.make_jaxpr(
         lambda A, h, s: countsketch_apply(A, h, s, d, interpret=True))(A, h, s))
     assert "sort" not in text and "gather" not in text
+
+
+@pytest.mark.parametrize("m,n,tiles,near", [
+    (9000, 200, "ragged", False),  # by columns
+    (8192, 200, "whole", False),
+    (5000, 1000, "ragged", False),  # by columns at fig3 width
+    (1000, 200, "short", False),  # one tile, shorter than a full one
+    (9000, 256, "ragged", False),  # by rows
+    (300, 256, "short", False),
+    (5000, 37, "ragged", False),  # n not a multiple of eight
+    (5000, 1000, "ragged", True),  # near the least-squares solution
+    (9000, 256, "ragged", True),
+])
+def test_residual_pass(m, n, tiles, near):
+    """One pass gives Aᵀ(b − A x) and ‖b − A x‖² as the two products do,
+    on either layout, with ragged and short m, and near convergence, where
+    g is a small difference of large terms."""
+    A = jax.random.normal(jax.random.key(1), (m, n), jnp.float32)
+    b = jax.random.normal(jax.random.key(2), (m,), jnp.float32)
+    if near:  # x rounded from the f64 solution: g is rounding noise
+        x = jnp.linalg.lstsq(A.astype(jnp.float64), b.astype(jnp.float64))[0]
+        x = x.astype(jnp.float32)
+    else:
+        x = jax.random.normal(jax.random.key(3), (n,), jnp.float32)
+    tm = _tile_rows(m, n, stored_by_columns(m, n))
+    assert tiles == ("short" if tm == m else "ragged" if m % tm else "whole")
+    g, rsq = residual_pass(A, x, b, interpret=True)
+    assert g.shape == (n,) and g.dtype == rsq.dtype == jnp.float32
+    g64, rsq64 = residual_pass_ref(*(v.astype(jnp.float64) for v in (A, x, b)))
+    g32, rsq32 = residual_pass_ref(A, x, b)  # the two f32 products
+    assert abs(float(rsq) - float(rsq64)) <= 1e-5 * float(rsq64)
+    err = float(jnp.linalg.norm(g - g64))
+    if near:
+        # no worse than XLA's two f32 products, which differ from the f64
+        # gradient by far more than the gradient itself here
+        err32 = float(jnp.linalg.norm(g32 - g64))
+        assert float(jnp.linalg.norm(g64)) < err32
+        assert err <= 2 * err32, (err, err32)
+    else:
+        assert err <= 1e-5 * float(jnp.linalg.norm(g64))
 
 
 @pytest.mark.parametrize("m", [8, 64, 512, 2048, 8192])

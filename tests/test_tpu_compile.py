@@ -21,10 +21,11 @@ from repro.kernels import (
     countsketch_apply,
     fused_gaussian_sketch,
     hadamard_transform,
+    residual_pass,
     sketch_matmul,
 )
 from repro.core import backend as backend_lib
-from repro.core import sketched_lstsq
+from repro.core import iterative_sketching, sketched_lstsq
 from repro.kernels.tsqr import countsketch_gram, gaussian_gram
 from repro.sharding import make_mesh
 
@@ -99,6 +100,57 @@ def test_countsketch_reads_a_where_it_lies(one_chip, shape, dtype):
     args, temp = _footprint(compiled)
     assert "tpu_custom_call" in compiled.as_text()
     assert temp <= 0.01 * args, (args, temp)
+
+
+@pytest.mark.parametrize("shape", [(M_FULL, N), (M_FULL, 1024)])
+def test_residual_pass_reads_a_where_it_lies(one_chip, shape):
+    """The one-pass residual kernel at fig3 width (A stored by columns)
+    and at a width stored by rows: no relayout copy of A, whose 4.2 GB
+    would show in the temporaries."""
+    m, n = shape
+    A, x, b = (_spec(one_chip, s, jnp.float32) for s in (shape, (n,), (m,)))
+    compiled = _compile(
+        lambda A, x, b: residual_pass(A, x, b, interpret=False), A, x, b)
+    args, temp = _footprint(compiled)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert temp <= 0.01 * args, (args, temp)
+
+
+def _computations(text: str) -> dict[str, list[str]]:
+    """The instructions of each computation of compiled HLO text."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            name = line.split()[1 if line.startswith("ENTRY") else 0]
+            comps[name.lstrip("%")] = []
+        elif name is not None:
+            comps[name.lstrip("%")].append(line.strip())
+    return comps
+
+
+def test_refinement_reads_a_once_per_iteration_at_fig3_width(one_chip):
+    """iterative_sketching at 2^20 x 1000 f32, compiled with the Pallas
+    kernels: the residual_pass kernel is in the body of the refinement's
+    while loop, and once outside it for the returned iterate, and the
+    program fits one chip with no copy of A."""
+    A = _spec(one_chip, (M_FULL, N), jnp.float32)
+    b = _spec(one_chip, (M_FULL,), jnp.float32)
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=one_chip)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(backend_lib, "default_interpret", lambda platform=None: False)
+        compiled = _compile(
+            partial(iterative_sketching, backend="pallas"), A, b, key)
+    comps = _computations(compiled.as_text())
+    bodies = {line.split("body=%")[1].split(",")[0]
+              for lines in comps.values() for line in lines
+              if " while(" in line}
+    homes = [name for name, lines in comps.items() for line in lines
+             if line.startswith("%residual_pass")
+             and 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(h in bodies for h in homes) == [False, True], homes
+    args, temp = _footprint(compiled)
+    assert temp <= 0.01 * args, (args, temp)
+    assert args + temp < HBM_BYTES
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
